@@ -90,6 +90,9 @@ def main() -> None:
 
     import jax
     jax.config.update("jax_enable_x64", True)
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
 
     if opts["json"]:
         # The JSON trajectory path: one deterministic document, written

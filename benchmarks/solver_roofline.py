@@ -18,13 +18,13 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json, jax
 from repro.core.spmv import lower_pcg_step
-from repro.launch.mesh import compat_make_mesh
+from repro.launch.mesh import PRODUCTION_DEVICE_KIND, compat_make_mesh
 from repro.launch.roofline import analyze
 mesh = compat_make_mesh((2,2,2), ("pod","data","model"))
 out = {}
 for mode in ("nvm", "inmemory"):
     compiled = lower_pcg_step(mesh, 64, 64, 64, esr_mode=mode).compile()
-    r = analyze(compiled, 8)
+    r = analyze(compiled, 8, PRODUCTION_DEVICE_KIND)
     ma = compiled.memory_analysis()
     out[mode] = {
         "coll_bytes": r.coll_bytes,
@@ -92,6 +92,9 @@ def rows():
     env["PYTHONPATH"] = os.pathsep.join(
         ["src"] + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
     env.pop("XLA_FLAGS", None)
+    # placeholder host devices only: never reach for an accelerator the
+    # parent may hold
+    env["JAX_PLATFORMS"] = "cpu"
     res = subprocess.run([sys.executable, "-c", _SUB], capture_output=True,
                          text=True, env=env, check=True)
     data = json.loads(res.stdout.strip().splitlines()[-1])

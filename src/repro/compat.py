@@ -1,21 +1,12 @@
-"""Version shims for the installed jax.
-
-The repo targets the modern public API (``jax.shard_map`` with
-``check_vma``); older jax (< 0.5) ships the same primitive as
-``jax.experimental.shard_map.shard_map`` with ``check_rep``.  Route all
-call sites through :func:`shard_map` so both work.
-"""
+"""The jax API seam: every ``shard_map`` call site routes through
+:func:`shard_map` (repro-lint RL101), so a jax API change is absorbed
+here once.  Written against the installed jax (0.9)."""
 from __future__ import annotations
 
 import jax
 
 
 def shard_map(f, mesh, in_specs, out_specs):
-    """``jax.shard_map`` with replication checking disabled, on any jax."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
+    """``jax.shard_map`` with replication checking disabled."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

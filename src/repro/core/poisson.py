@@ -22,15 +22,28 @@ import jax.numpy as jnp
 import numpy as np
 
 
+@jax.jit
 def stencil7(u: jax.Array) -> jax.Array:
     """7-point Poisson stencil with homogeneous Dirichlet boundary.
 
     ``(A u)[i,j,k] = 6 u[i,j,k] - sum of 6 face neighbours`` on a
     ``(nz, ny, nx)`` grid; out-of-domain neighbours are zero.
+
+    ``6 u`` is formed by additions only (``2u`` and ``4u`` are exact, so
+    the sum rounds once, like ``6.0 * u``).  A multiply here lets XLA's
+    CPU backend contract ``6 u - a`` into a fused multiply-add in some
+    fusions and not in others, and whether it does depends on the
+    sharding — which broke the sharded bit-exactness contract
+    (DESIGN.md §10).
+
+    Jitted so that an eager call (``init_state``, recovery) compiles the
+    pad and the shifts as one program: the TPU compiler aborts on an
+    eager ``jnp.pad`` of a z-sharded f64 grid on its own.
     """
     p = jnp.pad(u, 1)
+    u2 = u + u
     return (
-        6.0 * u
+        (u2 + u2) + u2
         - p[:-2, 1:-1, 1:-1]
         - p[2:, 1:-1, 1:-1]
         - p[1:-1, :-2, 1:-1]

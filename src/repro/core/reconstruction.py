@@ -16,7 +16,7 @@ blocks or matrix-free local CG for large ones.
 from __future__ import annotations
 
 from functools import partial
-from typing import Sequence, Tuple
+from typing import Dict, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -25,8 +25,15 @@ import numpy as np
 from repro.core.state import PCGState
 
 
+#: the most recent replacement-node CG solve: ``iterations`` taken and
+#: the ``maxiter`` cap.  A solve that used every iteration stopped short
+#: of ``tol`` (the relative residual floor the precision allows).
+last_local_cg: Dict[str, int] = {}
+
+
 def _local_cg(apply_fn, rhs: jax.Array, tol: float = 1e-14, maxiter: int = 10000) -> jax.Array:
-    """Matrix-free CG on the failed-block operator (replacement-node solve)."""
+    """Matrix-free CG on the failed-block operator (replacement-node
+    solve); records its iteration count in :data:`last_local_cg`."""
 
     def body(carry):
         x, r, p, rs, it = carry
@@ -48,7 +55,8 @@ def _local_cg(apply_fn, rhs: jax.Array, tol: float = 1e-14, maxiter: int = 10000
     # repro-lint: noqa[RL201] -- replacement-node local solve: single-block, single-device by construction
     rs0 = jnp.vdot(rhs, rhs)
     init = (x0, rhs, rhs, rs0, jnp.asarray(0))
-    x, *_ = jax.lax.while_loop(cond, body, init)
+    x, _, _, _, it = jax.lax.while_loop(cond, body, init)
+    last_local_cg.update(iterations=int(it), maxiter=maxiter)
     return x
 
 

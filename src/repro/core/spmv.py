@@ -46,34 +46,65 @@ def _grid_sharding(mesh: Mesh, shard_axes) -> NamedSharding:
 # The zoo's bit-exactness contract — a sharded solve reproduces the
 # unsharded trajectory exactly — therefore pins the order explicitly:
 #
-# 1. per-block partial sums (``reshape(nblocks, -1).sum(axis=1)``):
-#    each partial is computed entirely within one block, which the
-#    ``data``-mesh layout never splits across devices, so the partials
-#    are bitwise identical under any 1-D block sharding;
-# 2. an explicit replication constraint gathers the partials (the only
+# 1. per-block partial sums, one block at a time: a loop over the
+#    device's blocks whose body reduces one block of fixed length.  The
+#    body is the same program whatever the number of blocks per device,
+#    so the partials are bitwise identical under any 1-D block sharding.
+#    A single ``reshape(nblocks, -1).sum(axis=1)`` is NOT enough: the
+#    TPU tiles that reduce by its row count (8 rows unsharded, 2 per
+#    device on four chips), and the tiling sets the summation order;
+# 2. sharded, the loop runs per device under ``shard_map`` and an
+#    explicit replication constraint gathers the partials (the only
 #    collective — an all-gather of ``nblocks`` scalars);
 # 3. an UNROLLED left-to-right add chain combines them.  ``jnp.sum``
 #    over the partials is NOT enough: XLA fuses it context-dependently
 #    and reassociates across shardings, which is exactly the
 #    nondeterminism being excluded.
 # ----------------------------------------------------------------------
+def _blockwise(nblocks: int, mesh: Optional[Mesh], block_fn, in_specs,
+               out_spec):
+    """``f(*vectors) -> (nblocks, ...)``: ``block_fn`` applied to each
+    block's slice (the last axis of every operand, in ``nblocks`` equal
+    blocks), one block per loop trip; per device under ``shard_map``
+    when ``mesh`` is given, then replicated."""
+
+    def local(nloc, *vs):
+        m = vs[-1].shape[-1] // nloc
+
+        def one(i):
+            return block_fn(*(jax.lax.dynamic_slice_in_dim(
+                v, i * m, m, axis=v.ndim - 1) for v in vs))
+
+        return jax.lax.map(one, jnp.arange(nloc))
+
+    if mesh is None:
+        return partial(local, nblocks)
+    nloc = nblocks // int(mesh.shape["data"])
+    sharded = compat.shard_map(partial(local, nloc), mesh, in_specs,
+                               out_spec)
+    rep = NamedSharding(mesh, P())
+    return lambda *vs: jax.lax.with_sharding_constraint(sharded(*vs), rep)
+
+
+def _left_to_right(parts: jax.Array) -> jax.Array:
+    """``parts[0] + parts[1] + ...`` over the leading axis, unrolled."""
+    acc = parts[0]
+    for i in range(1, parts.shape[0]):
+        acc = acc + parts[i]
+    return acc
+
+
 def make_det_dot(nblocks: int, mesh: Optional[Mesh] = None):
     """Build ``dot(a, b)``: a block-hierarchical, order-pinned inner
     product that is bitwise identical across device shardings (and
     equal to the unsharded result).  ``mesh`` is the 1-D ``data`` mesh
     of a sharded operator (None for single-device runs)."""
-    rep = None if mesh is None else NamedSharding(mesh, P())
+    partials = _blockwise(
+        # repro-lint: noqa[RL201] -- the order-pinned per-block partial itself: one fixed-length block per loop trip
+        nblocks, mesh, lambda a, b: jnp.sum(a * b),
+        (P("data"), P("data")), P("data"))
 
-    def det_dot(a: jax.Array, b: jax.Array) -> jax.Array:
-        partials = (a * b).reshape(nblocks, -1).sum(axis=1)
-        if rep is not None:
-            partials = jax.lax.with_sharding_constraint(partials, rep)
-        acc = partials[0]
-        for i in range(1, nblocks):
-            acc = acc + partials[i]
-        return acc
-
-    return det_dot
+    return lambda a, b: _left_to_right(partials(a, b))
 
 
 def make_det_rowdots(nblocks: int, mesh: Optional[Mesh] = None):
@@ -81,19 +112,11 @@ def make_det_rowdots(nblocks: int, mesh: Optional[Mesh] = None):
     w)`` for an ``(rows, n)`` matrix — the Arnoldi projection shape.  The
     per-row partials use the same block-hierarchical order, so the result
     is bitwise sharding-independent like the scalar form."""
-    rep = None if mesh is None else NamedSharding(mesh, P())
+    partials = _blockwise(
+        nblocks, mesh, lambda m_rows, w: (m_rows * w[None, :]).sum(axis=1),
+        (P(None, "data"), P("data")), P("data", None))
 
-    def det_rowdots(m_rows: jax.Array, w: jax.Array) -> jax.Array:
-        rows = m_rows.shape[0]
-        partials = (m_rows * w[None, :]).reshape(rows, nblocks, -1).sum(axis=2)
-        if rep is not None:
-            partials = jax.lax.with_sharding_constraint(partials, rep)
-        acc = partials[:, 0]
-        for i in range(1, nblocks):
-            acc = acc + partials[:, i]
-        return acc
-
-    return det_rowdots
+    return lambda m_rows, w: _left_to_right(partials(m_rows, w))
 
 
 def make_sharded_pcg_step(

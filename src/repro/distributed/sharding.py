@@ -193,8 +193,8 @@ class ShardLayout:
 
 
 def make_data_mesh(nshards: int) -> Mesh:
-    """A 1-D ``data`` mesh of ``nshards`` devices (jax-0.4.37-compatible
-    via ``compat_make_mesh``).  Raises ``ValueError`` when the runtime
+    """A 1-D ``data`` mesh of ``nshards`` devices (built by the jax API
+    seam, ``compat_make_mesh``).  Raises ``ValueError`` when the runtime
     has fewer devices — callers (tests) turn that into a clean skip."""
     from repro.launch.mesh import compat_make_mesh
 

@@ -10,8 +10,9 @@ reads/writes each of ``x, r, z`` plus ``p, ap`` several times:
 This kernel performs all four in **one pass over VMEM tiles**: 5n reads +
 3n writes (the theoretical minimum with a fused reduction), a ~1.5x cut
 of HBM traffic on the dominant term of the solver roofline.  The dual
-reduction is accumulated per-tile into a (grid,)-shaped partials vector
-(hierarchical reduction: VREG -> VMEM partial -> tiny jnp.sum epilogue).
+reduction is accumulated per-tile into one lane-wide (1, 128) partials
+row (hierarchical reduction: VREG -> VMEM partial row -> tiny jnp.sum
+epilogue); ``alpha`` rides in SMEM.
 
 Layout: inputs are viewed as ``(m, 128)`` — lane-aligned for the VPU;
 ``bm`` rows per tile (sublane-multiple).  ``inv_diag`` supports any
@@ -19,14 +20,15 @@ diagonal preconditioner (Jacobi); pass ones for plain CG.
 """
 from __future__ import annotations
 
-import functools
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-LANES = 128
+from repro.kernels.gf256_encode import gf_xtime_packed
+from repro.kernels.tiling import LANES, block_at, reject_f64
 
 #: default row-tile cap: tiles never exceed this many (·, 128) rows
 DEFAULT_BM = 256
@@ -44,7 +46,10 @@ def largest_divisor_bm(m: int, cap: int = DEFAULT_BM) -> int:
 
 def _fused_cg_kernel(x_ref, r_ref, p_ref, ap_ref, inv_ref, alpha_ref,
                      xo_ref, ro_ref, zo_ref, partial_ref):
-    alpha = alpha_ref[0]
+    """The update body both kernels share: ``x' = x + a p``, ``r' = r - a ap``,
+    ``z' = r' * inv``, and this tile's lane-wise ``<r', z'>`` partials
+    (one (1, 128) row; the ``jnp.sum`` epilogue finishes the dot)."""
+    alpha = alpha_ref[0, 0]
     p = p_ref[...]
     ap = ap_ref[...]
     xn = x_ref[...] + alpha * p
@@ -55,7 +60,17 @@ def _fused_cg_kernel(x_ref, r_ref, p_ref, ap_ref, inv_ref, alpha_ref,
     zo_ref[...] = zn
     # fp32 accumulation for the dual reduction (bf16 partial sums of
     # near-cancelling terms would destroy CG's beta)
-    partial_ref[0, 0] = jnp.sum(rn.astype(jnp.float32) * zn.astype(jnp.float32))
+    prod = rn.astype(jnp.float32) * zn.astype(jnp.float32)
+    partial_ref[0] = jnp.sum(prod, axis=0, keepdims=True)
+
+
+def _alpha_operand(alpha, dtype):
+    """``alpha`` as the (1, 1) SMEM scalar both kernels read."""
+    return jnp.broadcast_to(jnp.asarray(alpha, dtype), (1, 1))
+
+
+_ALPHA_SPEC = pl.BlockSpec((1, 1), block_at(2, axis=None),
+                           memory_space=pltpu.SMEM)
 
 
 def fused_cg_update_pallas(
@@ -76,6 +91,8 @@ def fused_cg_update_pallas(
     An explicit ``bm`` that does not divide ``m`` still raises — that
     is a caller bug, not a size to silently repair.
     """
+    if not interpret:
+        reject_f64("fused_cg_update_pallas", x, r, p, ap, inv_diag)
     n = x.shape[0]
     if n % LANES != 0:
         raise ValueError(f"n={n} must be a multiple of {LANES}")
@@ -92,28 +109,26 @@ def fused_cg_update_pallas(
     def as2d(v):
         return v.reshape(m, LANES)
 
-    vec_spec = pl.BlockSpec((bm, LANES), lambda i: (i, 0))
-    alpha_arr = jnp.broadcast_to(jnp.asarray(alpha, x.dtype), (1,))
+    vec_spec = pl.BlockSpec((bm, LANES), block_at(2))
 
     xo, ro, zo, partials = pl.pallas_call(
         _fused_cg_kernel,
         grid=(grid,),
-        in_specs=[
-            vec_spec, vec_spec, vec_spec, vec_spec, vec_spec,
-            pl.BlockSpec((1,), lambda i: (0,)),
-        ],
+        in_specs=[vec_spec] * 5 + [_ALPHA_SPEC],
         out_specs=[
             vec_spec, vec_spec, vec_spec,
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, LANES), block_at(3)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((m, LANES), x.dtype),
             jax.ShapeDtypeStruct((m, LANES), x.dtype),
             jax.ShapeDtypeStruct((m, LANES), x.dtype),
-            jax.ShapeDtypeStruct((grid, 1), jnp.float32),
+            jax.ShapeDtypeStruct((grid, 1, LANES), jnp.float32),
         ],
+        name="fused_cg_update",
         interpret=interpret,
-    )(as2d(x), as2d(r), as2d(p), as2d(ap), as2d(inv_diag), alpha_arr)
+    )(as2d(x), as2d(r), as2d(p), as2d(ap), as2d(inv_diag),
+      _alpha_operand(alpha, x.dtype))
 
     rz = jnp.sum(partials).astype(x.dtype)  # tiny fp32 epilogue
     return xo.reshape(n), ro.reshape(n), zo.reshape(n), rz
@@ -128,41 +143,30 @@ def fused_cg_update_pallas(
 # numpy pass.  The emitted chunk and parity layouts are byte-identical
 # to ``ErasureSession._shards`` + ``gf256.rs_encode``.
 # ----------------------------------------------------------------------
-def _make_persist_kernel(k_data: int, nparity: int, chunk: int,
-                         itemsize: int):
+def _make_persist_kernel(k_data: int, nparity: int, chunk_rows: int):
     def kernel(x_ref, r_ref, p_ref, ap_ref, inv_ref, alpha_ref,
-               exp_ref, log_ref,
                xo_ref, ro_ref, zo_ref, partial_ref, ch_ref, par_ref):
-        alpha = alpha_ref[0]
-        p = p_ref[...]
-        ap = ap_ref[...]
-        xn = x_ref[...] + alpha * p
-        rn = r_ref[...] - alpha * ap
-        zn = rn * inv_ref[...]
-        xo_ref[...] = xn
-        ro_ref[...] = rn
-        zo_ref[...] = zn
-        partial_ref[0, 0] = jnp.sum(rn.astype(jnp.float32)
-                                    * zn.astype(jnp.float32))
-        # --- staging free rider: this tile IS one partition block of p
-        stripe = p.reshape(k_data, chunk)
-        ch_ref[0] = stripe
-        dbytes = jax.lax.bitcast_convert_type(
-            stripe, jnp.uint8).reshape(k_data, chunk * itemsize)
-        pp = dbytes[0]
-        for j in range(1, k_data):
-            pp = pp ^ dbytes[j]
+        _fused_cg_kernel(x_ref, r_ref, p_ref, ap_ref, inv_ref, alpha_ref,
+                         xo_ref, ro_ref, zo_ref, partial_ref)
+        # --- staging free rider: this tile IS one partition block of
+        # p, and stripe chunk j is its row band j (whole 128-lane rows)
+        words = []
+        for j in range(k_data):
+            cj = p_ref[j * chunk_rows:(j + 1) * chunk_rows, :]
+            ch_ref[0, j] = cj
+            # the chunk's bytes as packed uint32 words; bytewise XOR and
+            # xtime never cross a byte, so byte order is preserved
+            w = jax.lax.bitcast_convert_type(cj, jnp.uint32)
+            words.append(w.reshape(chunk_rows, -1))
+        pp = words[0]
+        for w in words[1:]:
+            pp = pp ^ w
         par_ref[0, 0] = pp
         if nparity == 2:
-            exp = exp_ref[...]
-            logt = log_ref[...]
-            q = None
-            for j in range(k_data):
-                dj = dbytes[j]
-                idx = jnp.take(logt, dj.astype(jnp.int32)) + (j % 255)
-                term = jnp.take(exp, idx).astype(jnp.uint8)
-                term = jnp.where(dj == jnp.uint8(0), jnp.uint8(0), term)
-                q = term if q is None else q ^ term
+            # Q = sum_j g^j d_j by Horner's rule from the last shard
+            q = words[-1]
+            for w in reversed(words[:-1]):
+                q = gf_xtime_packed(q) ^ w
             par_ref[0, 1] = q
 
     return kernel
@@ -193,11 +197,13 @@ def fused_cg_update_persist_pallas(
     The grid runs one partition block per step (tile rows =
     ``block_size // 128``), so the stripe chunking aligns with the
     update tiling; sizes that break that alignment (``128 ∤
-    block_size`` or ``k_data ∤ block_size``) raise and callers fall
-    back to the unfused path (DESIGN.md §13).
+    block_size`` or chunks that are not whole 128-lane rows) raise and
+    callers fall back to the unfused path (DESIGN.md §13).
     """
     from repro.nvm import gf256
 
+    if not interpret:
+        reject_f64("fused_cg_update_persist_pallas", x, r, p, ap, inv_diag)
     n = x.shape[0]
     if n % nblocks != 0:
         raise ValueError(f"n={n} not divisible by nblocks={nblocks}")
@@ -206,55 +212,60 @@ def fused_cg_update_persist_pallas(
         raise ValueError(
             f"block_size={bs} must be a multiple of {LANES} for the "
             f"fused persist pass")
-    if bs % k_data != 0:
-        raise ValueError(
-            f"block_size={bs} not divisible by k_data={k_data}: the "
-            f"stripe pads chunks, which the fused pass does not model")
-    gf256.vandermonde(nparity, k_data)
-    chunk = bs // k_data
-    itemsize = jnp.dtype(x.dtype).itemsize
     rb = bs // LANES
+    if rb % k_data != 0:
+        raise ValueError(
+            f"block rows {rb} (block_size={bs}) not divisible by "
+            f"k_data={k_data}: the fused pass stages whole 128-lane "
+            f"rows per stripe chunk")
+    itemsize = jnp.dtype(x.dtype).itemsize
+    if itemsize not in (4, 8):
+        raise ValueError(
+            f"the fused persist pass packs 4- or 8-byte values into "
+            f"uint32 words, got {jnp.dtype(x.dtype).name}")
+    gf256.vandermonde(nparity, k_data)
+    chunk_rows = rb // k_data
+    words_per_row = LANES * itemsize // 4
     m = n // LANES
 
     def as2d(v):
         return v.reshape(m, LANES)
 
-    vec_spec = pl.BlockSpec((rb, LANES), lambda i: (i, 0))
-    table = lambda size: pl.BlockSpec((size,), lambda i: (0,))  # noqa: E731
-    alpha_arr = jnp.broadcast_to(jnp.asarray(alpha, x.dtype), (1,))
-    exp = jnp.asarray(gf256.EXP, dtype=jnp.int32)
-    logt = jnp.asarray(gf256.LOG, dtype=jnp.int32)
+    vec_spec = pl.BlockSpec((rb, LANES), block_at(2))
 
     xo, ro, zo, partials, chunks, parity = pl.pallas_call(
-        _make_persist_kernel(k_data, nparity, chunk, itemsize),
+        _make_persist_kernel(k_data, nparity, chunk_rows),
         grid=(nblocks,),
-        in_specs=[
-            vec_spec, vec_spec, vec_spec, vec_spec, vec_spec,
-            pl.BlockSpec((1,), lambda i: (0,)),
-            table(510), table(256),
-        ],
+        in_specs=[vec_spec] * 5 + [_ALPHA_SPEC],
         out_specs=[
             vec_spec, vec_spec, vec_spec,
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-            pl.BlockSpec((1, k_data, chunk), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, nparity, chunk * itemsize),
-                         lambda i: (i, 0, 0)),
+            pl.BlockSpec((1, 1, LANES), block_at(3)),
+            pl.BlockSpec((1, k_data, chunk_rows, LANES),
+                         block_at(4)),
+            pl.BlockSpec((1, nparity, chunk_rows, words_per_row),
+                         block_at(4)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((m, LANES), x.dtype),
             jax.ShapeDtypeStruct((m, LANES), x.dtype),
             jax.ShapeDtypeStruct((m, LANES), x.dtype),
-            jax.ShapeDtypeStruct((nblocks, 1), jnp.float32),
-            jax.ShapeDtypeStruct((nblocks, k_data, chunk), x.dtype),
-            jax.ShapeDtypeStruct((nblocks, nparity, chunk * itemsize),
-                                 jnp.uint8),
+            jax.ShapeDtypeStruct((nblocks, 1, LANES), jnp.float32),
+            jax.ShapeDtypeStruct((nblocks, k_data, chunk_rows, LANES),
+                                 x.dtype),
+            jax.ShapeDtypeStruct((nblocks, nparity, chunk_rows,
+                                  words_per_row), jnp.uint32),
         ],
+        name="fused_cg_update_persist",
         interpret=interpret,
-    )(as2d(x), as2d(r), as2d(p), as2d(ap), as2d(inv_diag), alpha_arr,
-      exp, logt)
+    )(as2d(x), as2d(r), as2d(p), as2d(ap), as2d(inv_diag),
+      _alpha_operand(alpha, x.dtype))
 
     rz = jnp.sum(partials).astype(x.dtype)
-    return xo.reshape(n), ro.reshape(n), zo.reshape(n), rz, chunks, parity
+    chunk = bs // k_data
+    parity = jax.lax.bitcast_convert_type(parity, jnp.uint8).reshape(
+        nblocks, nparity, chunk * itemsize)
+    return (xo.reshape(n), ro.reshape(n), zo.reshape(n), rz,
+            chunks.reshape(nblocks, k_data, chunk), parity)
 
 
 def fused_pass_traffic(n: int, itemsize: int, k_data: int,

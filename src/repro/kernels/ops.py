@@ -1,9 +1,10 @@
 """Jit'd dispatch wrappers for the Pallas kernels.
 
-On TPU the Pallas path compiles natively; on CPU (this container) the
-kernels run under ``interpret=True`` (the kernel body executed step-by-
-step for correctness) or fall back to the jnp reference for speed.
-``mode`` resolution:
+On TPU ``"auto"`` and ``"pallas"`` run the compiled kernel or raise
+(an f64 operand raises before lowering); nothing there is interpreted
+and nothing gives way to the reference.  Off TPU — the CPU-test seam
+only — the kernels run under ``interpret=True`` (the kernel body
+executed step by step for correctness).  ``mode`` resolution:
 
 - ``"auto"``    — pallas on TPU, reference on CPU (fast tests/benches)
 - ``"pallas"``  — force the kernel (interpret=True off-TPU): oracle tests

@@ -1,13 +1,18 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 # ^ MUST precede any jax import: jax locks the device count on first init.
-#   512 placeholder host devices back the (2,16,16) production mesh.
+#   512 placeholder host devices back the (2,16,16) production mesh; the
+#   dry-run never touches an accelerator, even on a machine that has one.
 
 """Multi-pod dry-run: prove the distribution config is coherent.
 
 For every (architecture x applicable input shape) cell — and the paper's
 own PCG solver cells — this lowers and compiles the jitted step on the
-production mesh (single-pod 16x16 and multi-pod 2x16x16), prints
+production mesh (single-pod 16x16 and multi-pod 2x16x16) of host
+placeholder devices, prices the roofline against the production chip
+(:data:`~repro.launch.mesh.PRODUCTION_DEVICE_KIND`, named in every row),
+prints
 ``memory_analysis()`` (fits/doesn't fit) and ``cost_analysis()`` (FLOPs,
 bytes), extracts collective bytes from the partitioned HLO, and appends
 one JSON row per cell to ``results/dryrun.jsonl`` for EXPERIMENTS.md.
@@ -28,7 +33,7 @@ from typing import Optional
 import jax
 
 from repro.distributed.sharding import set_rules, use_rules
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import PRODUCTION_DEVICE_KIND, make_production_mesh
 from repro.launch import roofline as RL
 from repro.models import registry as R
 
@@ -117,6 +122,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     row = {
         "arch": label, "shape": shape_name,
         "mesh": "2x16x16" if multi_pod else "16x16", "chips": chips,
+        "device_kind": PRODUCTION_DEVICE_KIND,
         "compile_s": round(dt, 1),
         "memory": mem,
         "ok": True,
@@ -125,8 +131,8 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     if calibrate:
         c1, _ = _compile_cell(_depth_variant(cfg, 1), arch, shape_name, rules, mesh)
         c2, _ = _compile_cell(_depth_variant(cfg, 2), arch, shape_name, rules, mesh)
-        r1 = RL.analyze(c1, chips)
-        r2 = RL.analyze(c2, chips)
+        r1 = RL.analyze(c1, chips, PRODUCTION_DEVICE_KIND)
+        r2 = RL.analyze(c2, chips, PRODUCTION_DEVICE_KIND)
         period = cfg.group_size
         groups_eff = cfg.n_groups + cfg.n_tail / period
         if cfg.family == "encdec":
@@ -148,6 +154,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
             coll_bytes=float(sum(colls.values())),
             coll_by_kind=colls,
             chips=chips,
+            device_kind=PRODUCTION_DEVICE_KIND,
         )
         mflops = RL.model_flops(cfg, cell.shape, cell.shape.kind)
         row.update({
@@ -192,7 +199,7 @@ def run_solver_cell(grid_name: str, multi_pod: bool,
     compiled = lowered.compile()
     dt = time.monotonic() - t0
     mem = _memory_row(compiled)
-    roof = RL.analyze(compiled, chips)
+    roof = RL.analyze(compiled, chips, PRODUCTION_DEVICE_KIND)
     n = nz * ny * nx
     # PCG iteration useful flops: SpMV(7pt: 7 mul+6 add ~ 13/pt... count 2*nnz
     # = 14n) + 2 dots (4n) + 3 axpy (6n) + precond (n)  => ~25n flops global
@@ -200,6 +207,7 @@ def run_solver_cell(grid_name: str, multi_pod: bool,
     row = {
         "arch": "poisson_pcg", "shape": grid_name,
         "mesh": "2x16x16" if multi_pod else "16x16", "chips": chips,
+        "device_kind": PRODUCTION_DEVICE_KIND,
         "esr_mode": sc.esr_mode,
         "compile_s": round(dt, 1),
         "memory": mem,

@@ -17,9 +17,7 @@ import json
 import sys
 from typing import Dict, Optional
 
-from repro.launch.mesh import HBM_BW, ICI_BW_PER_LINK, PEAK_FLOPS_BF16
-
-HBM_PER_CHIP = 16 * 2**30  # v5e
+from repro.launch.mesh import chip_peaks
 
 
 def load(path: str) -> Dict:
@@ -55,19 +53,20 @@ def table(rows: Dict, mesh: str = "16x16", corrected: bool = True) -> str:
         if m != mesh or "roofline" not in r:
             continue
         rf = r["roofline"]
+        peaks = chip_peaks(r["device_kind"])
         bf16 = a != "poisson_pcg"
         coll = corrected_coll_bytes(r, bf16) if corrected else rf["coll_bytes_per_chip"]
         hbm = rf["hbm_bytes_per_chip"] * (0.5 if (corrected and bf16) else 1.0)
-        tc = rf["flops_per_chip"] / PEAK_FLOPS_BF16
-        tm = hbm / HBM_BW
-        tx = (coll or 0) / ICI_BW_PER_LINK
+        tc = rf["flops_per_chip"] / peaks.flops_bf16
+        tm = hbm / peaks.hbm_bytes_per_s
+        tx = (coll or 0) / peaks.ici_bytes_per_s_per_link
         terms = {"compute": tc, "memory": tm, "collective": tx}
         bneck = max(terms, key=terms.get)
         peak = r["memory"].get("peak_bytes", 0)
-        fits = "Y" if peak <= HBM_PER_CHIP else "n"
+        fits = "Y" if peak <= peaks.hbm_bytes else "n"
         mf = r.get("model_flops_per_chip") or 0
         uf = r.get("useful_flop_ratio")
-        t_useful = mf / PEAK_FLOPS_BF16
+        t_useful = mf / peaks.flops_bf16
         frac = t_useful / max(tc, tm, tx) if max(tc, tm, tx) > 0 else 0
         out.append(
             f"| {a} | {s} | {peak/2**30:.2f} | {fits} | {tc*1e3:.1f} | "

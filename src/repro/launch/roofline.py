@@ -19,7 +19,7 @@ import dataclasses
 import re
 from typing import Dict, Optional
 
-from repro.launch.mesh import HBM_BW, ICI_BW_PER_LINK, PEAK_FLOPS_BF16
+from repro.launch.mesh import ChipPeaks, chip_peaks
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2,
@@ -78,20 +78,28 @@ class Roofline:
     coll_bytes: float
     coll_by_kind: Dict[str, int]
     chips: int
+    device_kind: str   # the target chip, keyed into CHIP_PEAKS
+
+    def __post_init__(self):
+        chip_peaks(self.device_kind)  # an unknown kind raises here
+
+    @property
+    def peaks(self) -> ChipPeaks:
+        return chip_peaks(self.device_kind)
 
     @property
     def t_compute(self) -> float:
-        return self.flops / PEAK_FLOPS_BF16
+        return self.flops / self.peaks.flops_bf16
 
     @property
     def t_memory(self) -> float:
-        return self.hbm_bytes / HBM_BW
+        return self.hbm_bytes / self.peaks.hbm_bytes_per_s
 
     @property
     def t_collective(self) -> float:
         # per-chip collective bytes over one ICI link direction (the
         # bottleneck link on a 2-D torus for ring collectives)
-        return self.coll_bytes / ICI_BW_PER_LINK
+        return self.coll_bytes / self.peaks.ici_bytes_per_s_per_link
 
     @property
     def bottleneck(self) -> str:
@@ -116,8 +124,10 @@ class Roofline:
         }
 
 
-def analyze(compiled, chips: int) -> Roofline:
-    """Build the roofline terms from a compiled executable.
+def analyze(compiled, chips: int, device_kind: str) -> Roofline:
+    """Build the roofline terms from a compiled executable, against the
+    peaks of ``device_kind`` (the chip the program targets, which a
+    dry-run on host devices must name).
 
     ``cost_analysis`` reports the per-device (partitioned) program.
     """
@@ -133,6 +143,7 @@ def analyze(compiled, chips: int) -> Roofline:
         coll_bytes=float(sum(colls.values())),
         coll_by_kind=colls,
         chips=chips,
+        device_kind=device_kind,
     )
 
 

@@ -10,6 +10,8 @@ set -euo pipefail
 cd "$(dirname "$0")"
 
 export JAX_ENABLE_X64=1
+# Tests run on the host CPU; the program runs on the chip via chip_smoke.py.
+export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
 export XLA_FLAGS="${XLA_FLAGS:---xla_force_host_platform_device_count=8}"
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
 

@@ -19,8 +19,12 @@ import os
 import subprocess
 import sys
 
-import jax
-import pytest
+# Tests run on the host CPU (faked devices, interpreted kernels); the
+# program reaches the chip through chip_smoke.py.  Set before jax loads.
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
+import jax  # noqa: E402
+import pytest  # noqa: E402
 
 jax.config.update("jax_enable_x64", True)
 
@@ -63,6 +67,8 @@ class MultiDeviceRunner:
         env = dict(os.environ)
         env["PYTHONPATH"] = "src"
         env.pop("XLA_FLAGS", None)  # never inherit a stray device count
+        # faked host devices only: never reach for an accelerator
+        env["JAX_PLATFORMS"] = "cpu"
         return env
 
     def require(self, ndevices: int = 8) -> None:

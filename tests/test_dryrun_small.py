@@ -11,7 +11,7 @@ import json, jax
 import dataclasses as dc
 from repro.distributed.sharding import set_rules
 from repro.models import registry as R
-from repro.launch.mesh import compat_make_mesh
+from repro.launch.mesh import PRODUCTION_DEVICE_KIND, compat_make_mesh
 from repro.launch.roofline import analyze
 
 mesh = compat_make_mesh((2, 2, 2), ("pod", "data", "model"))
@@ -28,7 +28,7 @@ for arch, shape in (("llama3_8b", "train_4k"), ("llama3_8b", "decode_32k")):
     with mesh:
         compiled = jax.jit(cell.fn, in_shardings=cell.in_shardings,
                            donate_argnums=cell.donate).lower(*cell.in_structs).compile()
-    r = analyze(compiled, 8)
+    r = analyze(compiled, 8, PRODUCTION_DEVICE_KIND)
     ma = compiled.memory_analysis()
     out[f"{arch}/{shape}"] = {
         "flops": r.flops,
@@ -41,7 +41,7 @@ for arch, shape in (("llama3_8b", "train_4k"), ("llama3_8b", "decode_32k")):
 from repro.core.spmv import lower_pcg_step
 for variant in ("auto", "shardmap"):
     c = lower_pcg_step(mesh, 64, 32, 32, esr_mode="nvm", variant=variant).compile()
-    out[f"pcg/{variant}"] = {"colls": analyze(c, 8).coll_by_kind}
+    out[f"pcg/{variant}"] = {"colls": analyze(c, 8, PRODUCTION_DEVICE_KIND).coll_by_kind}
 
 print(json.dumps(out))
 """

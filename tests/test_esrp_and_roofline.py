@@ -75,12 +75,25 @@ def test_roofline_terms_and_bottleneck():
     from repro.launch.roofline import Roofline
 
     r = Roofline(flops=197e12, hbm_bytes=819e9 / 2, coll_bytes=50e9 * 2,
-                 coll_by_kind={}, chips=256)
+                 coll_by_kind={}, chips=256, device_kind="TPU v5 lite")
     assert abs(r.t_compute - 1.0) < 1e-9
     assert abs(r.t_memory - 0.5) < 1e-9
     assert abs(r.t_collective - 2.0) < 1e-9
     assert r.bottleneck == "collective"
     assert r.step_time_lb == r.t_collective
+
+
+def test_roofline_refuses_unknown_device_kind():
+    """Peaks are keyed by device_kind; a chip with no published entry
+    (or the host CPU) is an error, never a silent v5e default."""
+    from repro.launch.mesh import chip_peaks
+    from repro.launch.roofline import Roofline
+
+    assert chip_peaks("TPU v5 lite").hbm_bytes_per_s == 819e9
+    for kind in ("cpu", "TPU v4"):
+        with pytest.raises(ValueError, match="no published peaks"):
+            Roofline(flops=1.0, hbm_bytes=1.0, coll_bytes=0.0,
+                     coll_by_kind={}, chips=1, device_kind=kind)
 
 
 # ----------------------------------------------------------------------

@@ -99,7 +99,9 @@ def _stage_oracle(p, nblocks, k_data, nparity, dtype):
 @pytest.mark.parametrize("dtype", [jnp.float64, jnp.float32])
 def test_fused_persist_kernel_bit_identical(nblocks, k_data, nparity,
                                             dtype):
-    n = nblocks * 128 * 6  # bs = 768: divisible by 128, 2, 4 and 6
+    # bs = 1536 = 12 rows of 128: every stripe chunk is whole rows
+    # for K = 2, 4 and 6
+    n = nblocks * 128 * 12
     rng = np.random.default_rng(nblocks + k_data + nparity)
     x, r, p, ap, inv = (jnp.asarray(rng.standard_normal(n), dtype)
                         for _ in range(5))

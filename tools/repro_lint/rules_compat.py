@@ -1,9 +1,8 @@
 """RL1xx — jax version-compat isolation.
 
-The installed jax is 0.4.37: ``jax.shard_map`` and
-``jax.sharding.AxisType`` do not exist, and ``jax.make_mesh`` has no
-``axis_types`` kwarg (ROADMAP standing constraint).  The repo's answer
-is a single compat seam — ``repro/compat.py`` (:func:`shard_map`) and
+``jax.shard_map``, ``jax.sharding.AxisType`` and ``jax.make_mesh``'s
+``axis_types`` kwarg are the jax surfaces that have moved between
+releases.  The repo's answer is a single compat seam — ``repro/compat.py`` (:func:`shard_map`) and
 ``repro/launch/mesh.py`` (:func:`compat_make_mesh`) — and these rules
 keep every other file off the raw surfaces, so a jax upgrade or
 downgrade is a two-file change instead of a tree-wide audit.
@@ -39,9 +38,9 @@ def _jax_imports(ctx: FileContext) -> Tuple[Set[str], Set[str]]:
 class RawShardMapRule(Rule):
     rule_id = "RL101"
     title = "direct jax.shard_map outside the compat seam"
-    hint = "call repro.compat.shard_map (version-shimmed) instead"
-    invariant = "ROADMAP standing constraint: jax 0.4.37 has no " \
-                "jax.shard_map; all call sites route through repro.compat"
+    hint = "call repro.compat.shard_map (the API seam) instead"
+    invariant = "jax.shard_map has moved between jax releases; all " \
+                "call sites route through repro.compat"
 
     def check(self, ctx: FileContext) -> Iterable[Finding]:
         if ctx.path_endswith(*COMPAT_FILES):
@@ -52,8 +51,8 @@ class RawShardMapRule(Rule):
                     and isinstance(node.value, ast.Name)
                     and node.value.id == "jax"):
                 yield self.finding(
-                    ctx, node, "direct jax.shard_map reference — absent "
-                    "on the installed jax 0.4.37")
+                    ctx, node, "direct jax.shard_map reference — "
+                    "version-dependent surface")
             if isinstance(node, ast.ImportFrom) and node.module in (
                     "jax", "jax.experimental.shard_map"):
                 for alias in node.names:
@@ -66,10 +65,10 @@ class RawShardMapRule(Rule):
 class RawAxisTypeRule(Rule):
     rule_id = "RL102"
     title = "jax.sharding.AxisType outside the compat seam"
-    hint = "use repro.launch.mesh.compat_make_mesh, which applies " \
-           "AxisType only where the installed jax supports it"
-    invariant = "ROADMAP standing constraint: jax.sharding.AxisType " \
-                "does not exist before jax 0.5"
+    hint = "use repro.launch.mesh.compat_make_mesh, the one place " \
+           "axis types are chosen"
+    invariant = "jax.sharding.AxisType does not exist before jax 0.5; " \
+                "meshes are built in the compat seam only"
 
     def check(self, ctx: FileContext) -> Iterable[Finding]:
         if ctx.path_endswith(*COMPAT_FILES):
@@ -78,8 +77,8 @@ class RawAxisTypeRule(Rule):
             if (isinstance(node, ast.Attribute) and node.attr == "AxisType"
                     and ast.unparse(node.value) == "jax.sharding"):
                 yield self.finding(
-                    ctx, node, "jax.sharding.AxisType reference — absent "
-                    "on the installed jax 0.4.37")
+                    ctx, node, "jax.sharding.AxisType reference — "
+                    "version-dependent surface")
             if isinstance(node, ast.ImportFrom) \
                     and node.module == "jax.sharding":
                 for alias in node.names:
