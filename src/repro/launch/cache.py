@@ -1,9 +1,10 @@
 """Where JAX's persistent compilation cache lives.
 
 A compiled program is found again by a later process only when both
-look in the same directory, so the repository keeps exactly one: ``JAX_COMPILATION_CACHE_DIR`` when
-the environment sets it (JAX reads that variable itself, and nothing
-here overrides it), else the fixed ``<repo>/.jax_cache``.  Entry points
+look in the same directory, so the repository keeps exactly one:
+``JAX_COMPILATION_CACHE_DIR`` when the environment sets it (JAX reads
+that variable itself, and nothing here overrides it), else the fixed
+``<repo>/.jax_cache``.  Entry points
 that compile call :func:`enable_compile_cache` before their first
 compile; nothing else in the code sets a cache directory.
 """

@@ -2,8 +2,8 @@
 
 ``jax.shard_map``, ``jax.sharding.AxisType`` and ``jax.make_mesh``'s
 ``axis_types`` kwarg are the jax surfaces that have moved between
-releases.  The repo's answer is a single compat seam — ``repro/compat.py`` (:func:`shard_map`) and
-``repro/launch/mesh.py`` (:func:`compat_make_mesh`) — and these rules
+releases.  The repo's answer is a single compat seam —
+``repro/compat.py`` (:func:`shard_map`) and ``repro/launch/mesh.py`` (:func:`compat_make_mesh`) — and these rules
 keep every other file off the raw surfaces, so a jax upgrade or
 downgrade is a two-file change instead of a tree-wide audit.
 """
