@@ -34,6 +34,7 @@ from repro.nvm.backend import (
     UnrecoverableFailure,
     backend_names,
 )
+from repro.obs.trace import NULL_SPAN
 from repro.solvers import driver as _driver
 from repro.solvers.driver import (
     CampaignPlan,
@@ -360,8 +361,13 @@ def solve(
             f"re-shard with Problem.with_shards({resilience.nshards}) "
             f"or drop the spec's shard pin")
 
-    built_solver = solver.build(problem)
-    backend = resilience.build(problem, built_solver)
+    trace = tracer or None
+    # building the backend allocates its stores: host work that the
+    # solve pays before its first iteration
+    with (trace.span("solve.build", backend=resilience.backend)
+          if trace is not None else NULL_SPAN):
+        built_solver = solver.build(problem)
+        backend = resilience.build(problem, built_solver)
     config = SolveConfig(
         tol=solver.tol,
         maxiter=solver.maxiter,
@@ -369,7 +375,7 @@ def solve(
         persist_mode=resilience.persist_mode,
         plan_campaign=resilience.plan_campaigns,
         fused_persist=resilience.fused_persist,
-        tracer=tracer,
+        tracer=trace,
     )
     state, report, captured = _driver.solve(
         built_solver, problem.op, problem.b, problem.precond,

@@ -514,7 +514,7 @@ class CoreBackendSession(PersistSession):
         self._note_persist_traffic()
         cost = self._backend.persist_set(k, scalars, vectors)
         if self._trace is not None:
-            self._trace.event("backend.write", k=k, cost_s=cost,
+            self._trace.event("backend.write", k=k,
                               backend=type(self._backend).__name__)
         return cost
 
@@ -715,7 +715,7 @@ class ReplicatedSession(PersistSession):
             if s._storage_down:
                 continue
             c = s.commit()
-            self._trace.event("mirror.commit", mirror=i, cost_s=c)
+            self._trace.event("mirror.commit", mirror=i)
             cost += c
         return cost
 
@@ -1100,7 +1100,7 @@ class ErasureSession(PersistSession):
             c = getattr(self._children[child], method)(k, scalars, shards[j])
             if self._trace is not None:
                 self._trace.event("stripe.write", child=child, shard=j,
-                                  parity=j >= be.k_data, rot=rot, cost_s=c)
+                                  parity=j >= be.k_data, rot=rot)
             cost += c
         return cost
 

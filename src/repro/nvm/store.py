@@ -26,6 +26,8 @@ from typing import Callable, Dict, Mapping, Optional, Tuple
 
 import numpy as np
 
+from repro.obs.trace import NULL_SPAN
+
 
 class Tier(enum.Enum):
     DRAM = "dram"
@@ -269,37 +271,35 @@ class PersistStager:
             raise RuntimeError(
                 f"persist staging depth {self.DEPTH} exceeded: commit or "
                 f"drain before staging iteration {k}")
-        # A real copy, not a view: the caller may reuse its buffers while
-        # the staged payload waits for commit (the cost charged below IS
-        # this copy).
-        vecs = {name: np.array(v) for name, v in vectors.items()}
-        nbytes = 8 + 8 * len(scalars) + sum(v.nbytes for v in vecs.values())
+        nbytes = (8 + 8 * len(scalars)
+                  + sum(v.nbytes for v in vectors.values()))
+        # The staging copy is the exposed part of an overlapped event; the
+        # flush below is the hidden part (DESIGN.md §6).
+        with (self.tracer.span("stage.copy", k=int(k), nbytes=nbytes,
+                               exposed=True)
+              if self.tracer is not None else NULL_SPAN):
+            # A real copy, not a view: the caller may reuse its buffers
+            # while the staged payload waits for commit (the modeled stage
+            # cost IS this copy).
+            vecs = {name: np.array(v) for name, v in vectors.items()}
         self._staged.append((int(k), dict(scalars), vecs))
-        cost = self.cost.add("stage", self._dram.write_cost(nbytes))
-        if self.tracer is not None:
-            # The staging copy is the exposed part of an overlapped
-            # event; the flush below is the hidden part (DESIGN.md §6).
-            self.tracer.event("stage.copy", k=int(k), nbytes=nbytes,
-                              cost_s=cost, exposed=True)
-        return cost
+        return self.cost.add("stage", self._dram.write_cost(nbytes))
 
     def commit(self) -> float:
         if not self._staged:
             return 0.0
         k, scalars, vectors = self._staged.popleft()
-        cost = self._flush(k, scalars, vectors)
-        if self.tracer is not None:
-            self.tracer.event("stage.flush", k=int(k), cost_s=cost,
-                              exposed=False)
-        return cost
+        with (self.tracer.span("stage.flush", k=int(k), exposed=False)
+              if self.tracer is not None else NULL_SPAN):
+            return self._flush(k, scalars, vectors)
 
     def drain(self) -> float:
         total = 0.0
         drained = len(self._staged)
-        while self._staged:
-            total += self.commit()
-        if self.tracer is not None and drained:
-            self.tracer.event("stage.drain", events=drained, cost_s=total)
+        with (self.tracer.span("stage.drain", events=drained)
+              if self.tracer is not None and drained else NULL_SPAN):
+            while self._staged:
+                total += self.commit()
         return total
 
     def abort(self) -> int:

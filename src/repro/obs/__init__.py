@@ -22,6 +22,7 @@ from repro.obs.metrics import (  # noqa: F401
     check_trace_report,
 )
 from repro.obs.trace import (  # noqa: F401
+    NULL_SPAN,
     NULL_TRACER,
     NullTracer,
     Tracer,

@@ -1,9 +1,9 @@
 """Structured solve-pipeline tracing (DESIGN.md §9).
 
-A :class:`Tracer` records **nestable spans** (timed regions: an
-iteration's step, a recovery fetch, an RS decode) and **instant
-events** (a persist commit with its hidden/exposed attribution, a
-failure injection) with monotonic timestamps and JSON-safe labels.
+A :class:`Tracer` records **nestable spans** (timed regions: the
+residual pull, a persist commit, a recovery fetch, an RS decode) and
+**instant events** (a failure injection, a storage loss) with
+monotonic timestamps and JSON-safe labels.
 Export targets:
 
 - JSONL (:meth:`Tracer.to_jsonl` / :func:`from_jsonl`) — one record per
@@ -24,6 +24,13 @@ Span/event *names are string literals at every call site* — the docs
 freshness gate (``tools/check_docs.py``) scans ``src/`` textually for
 ``.span("...")`` / ``.event("...")`` and requires every name to appear
 in the docs/observability.md taxonomy table.
+
+**One clock with the device trace.**  Each span also opens a
+``jax.profiler.TraceAnnotation`` of the same name for its lifetime, so
+under ``jax.profiler.trace`` every span appears on the profiler's host
+plane, timed by the profiler's own clock beside the device's
+operations.  With the profiler off an annotation costs about a
+microsecond; without jax installed spans annotate nothing.
 """
 from __future__ import annotations
 
@@ -58,9 +65,11 @@ class _Span:
     """An open span: a reusable context manager bound to one tracer.
 
     Records the span *at close* (so the event list orders children
-    before their parent — reconstructible through ``depth``/``ts``)."""
+    before their parent — reconstructible through ``depth``/``ts``).
+    The profiler annotation opens before the start is read and closes
+    after the end is read, so it encloses the recorded interval."""
 
-    __slots__ = ("_tracer", "name", "args", "_start", "_depth")
+    __slots__ = ("_tracer", "name", "args", "_start", "_depth", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, args: Dict[str, Any]):
         self._tracer = tracer
@@ -68,15 +77,21 @@ class _Span:
         self.args = args
         self._start = 0.0
         self._depth = 0
+        self._ann = None
 
     def __enter__(self) -> "_Span":
         self._depth = self._tracer._depth
         self._tracer._depth += 1
+        if self._tracer._annotation is not None:
+            self._ann = self._tracer._annotation(self.name)
+            self._ann.__enter__()
         self._start = self._tracer._clock()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         end = self._tracer._clock()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         self._tracer._depth -= 1
         self._tracer._record({
             "type": "span",
@@ -94,13 +109,19 @@ class Tracer:
     Single-threaded by design (the driver is); timestamps come from a
     monotonic ``clock`` (``time.perf_counter`` by default — injectable
     for deterministic tests).  ``ts``/``dur`` are seconds relative to
-    the tracer's construction.
+    the tracer's construction; a span's ``dur`` is the wall time of its
+    region.
     """
 
     enabled = True
 
     def __init__(self, clock: Callable[[], float] = time.perf_counter):
         self._clock = clock
+        try:  # imported here: repro.obs (and repro.nvm) import without jax
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            TraceAnnotation = None
+        self._annotation = TraceAnnotation
         self._t0 = clock()
         self._depth = 0
         self.records: List[Dict[str, Any]] = []
@@ -197,7 +218,10 @@ def from_jsonl(path) -> List[Dict[str, Any]]:
 
 class _NullSpan:
     """The cached no-op context manager :meth:`NullTracer.span` returns —
-    one shared instance, so the disabled path allocates nothing."""
+    one shared instance, so the disabled path allocates nothing.  Record
+    sites that wrap work in a span use it as the disabled branch of one
+    ``with``: ``with (trace.span(...) if trace is not None else
+    NULL_SPAN):``."""
 
     __slots__ = ()
 
@@ -208,7 +232,7 @@ class _NullSpan:
         return None
 
 
-_NULL_SPAN = _NullSpan()
+NULL_SPAN = _NullSpan()
 
 
 class NullTracer:
@@ -224,7 +248,7 @@ class NullTracer:
         return False
 
     def span(self, name: str, **labels: Any) -> _NullSpan:
-        return _NULL_SPAN
+        return NULL_SPAN
 
     def event(self, name: str, **labels: Any) -> None:
         return None

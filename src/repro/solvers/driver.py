@@ -54,6 +54,7 @@ from repro.nvm.backend import (
     open_persist_session,
 )
 from repro.obs.metrics import MetricsRegistry
+from repro.obs.trace import NULL_SPAN
 
 PERSIST_MODES = ("sync", "overlap")
 
@@ -475,6 +476,10 @@ class SolveReport:
     - ``storage_failures`` — persistence-service (PRD-node) crashes
       injected by ``FailureEvent(prd=True)`` campaign events; survived
       only by backends declaring ``survives_prd_loss``.
+    - ``recovery_wall_s`` — wall seconds (host clock, not modeled) from
+      each block failure to the next step dispatch, summed over the
+      solve's recoveries (the ``recovery.wall_s`` histogram; its count
+      is the number of recoveries that reached a step).
     - ``converged`` — relative residual reached ``SolveConfig.tol``.
     - ``final_relres`` — ``||b - A x|| / ||b||`` proxy at exit
       (``solver.residual_norm / ||b||``).
@@ -550,6 +555,7 @@ class SolveReport:
     failures_recovered: int = 0
     recovery_restarts: int = 0
     storage_failures: int = 0
+    recovery_wall_s: float = 0.0
     converged: bool = False
     final_relres: float = float("nan")
     persist_cost_s: float = 0.0
@@ -739,6 +745,9 @@ class PersistencePipeline:
         self.last_persisted_k: Optional[int] = None
         self.consecutive = 0
         self.staged_state = None  # payload staged, pending commit
+        # host-clock times of block failures whose recovery has not yet
+        # reached a step dispatch (read into recovery.wall_s there)
+        self.inject_times: List[float] = []
         # Fused overlap only: persist point reached but staging deferred
         # into the next iteration's timed window (flush_pending_stage).
         # At most one of staged_state / pending_state is set at a time.
@@ -746,16 +755,13 @@ class PersistencePipeline:
 
     # ------------------------------------------------------------------
     def _note_committed(self, st, cost: float, window_s: float) -> None:
-        metrics, trace = self.metrics, self.trace
+        metrics = self.metrics
         metrics.histogram("persist.commit_s", phase="persist").observe(cost)
         metrics.counter("persist.commit").inc()
         hidden = min(cost, window_s)
         metrics.histogram("persist.hidden_s", phase="persist").observe(hidden)
         metrics.histogram("persist.exposed_s",
                           phase="persist").observe(cost - hidden)
-        if trace is not None:
-            trace.event("persist.commit", k=int(st.k), cost_s=cost,
-                        hidden_s=hidden, exposed_s=cost - hidden)
         k_c = int(st.k)
         self.consecutive = (self.consecutive + 1
                             if self.last_persisted_k == k_c - 1 else 1)
@@ -770,19 +776,26 @@ class PersistencePipeline:
             self.snapshot = st
 
     def persist_begin(self, st) -> None:
-        rset = self.solver.recovery_set(st)
-        stage_cost = self.session.begin(rset.k, rset.scalars, rset.vectors)
+        trace = self.trace
+        # persist.begin = the pull of the recovery set + the stage
+        with (trace.span("persist.begin", k=int(st.k))
+              if trace is not None else NULL_SPAN):
+            with (trace.span("persist.pull", k=int(st.k))
+                  if trace is not None else NULL_SPAN):
+                rset = self.solver.recovery_set(st)
+            stage_cost = self.session.begin(rset.k, rset.scalars,
+                                            rset.vectors)
         self.metrics.histogram("persist.stage_s",
                                phase="persist").observe(stage_cost)
-        trace = self.trace
-        if trace is not None:
-            trace.event("persist.begin", k=rset.k, stage_s=stage_cost)
         self.staged_state = st
 
     def persist_commit(self, window_s: float = 0.0) -> None:
         if self.staged_state is None:
             return
-        cost = self.session.commit()
+        trace = self.trace
+        with (trace.span("persist.commit", k=int(self.staged_state.k))
+              if trace is not None else NULL_SPAN):
+            cost = self.session.commit()
         self._note_committed(self.staged_state, cost, window_s)
         self.staged_state = None
 
@@ -827,8 +840,14 @@ class PersistencePipeline:
             else:
                 self.persist_begin(st)
         else:
-            rset = self.solver.recovery_set(st)
-            cost = self.session.persist(rset.k, rset.scalars, rset.vectors)
+            trace = self.trace
+            with (trace.span("persist.pull", k=int(st.k))
+                  if trace is not None else NULL_SPAN):
+                rset = self.solver.recovery_set(st)
+            with (trace.span("persist.commit", k=int(st.k))
+                  if trace is not None else NULL_SPAN):
+                cost = self.session.persist(rset.k, rset.scalars,
+                                            rset.vectors)
             self._note_committed(st, cost, 0.0)
 
     # ------------------------------------------------------------------
@@ -859,6 +878,8 @@ class PersistencePipeline:
         if self.session is None:
             raise RuntimeError(
                 "failure injected but no recovery backend configured")
+        if ev.blocks:
+            self.inject_times.append(time.perf_counter())
         trace = self.trace
         if trace is not None:
             trace.event("failure.inject", k=k, blocks=tuple(ev.blocks),
@@ -905,11 +926,11 @@ class PersistencePipeline:
                 session.fail(tuple(new))  # VM lost
             # Drain barrier: outstanding persistence settles (or is torn
             # away) before the durable recovery point is read.
-            drain_cost = session.drain()
+            with (trace.span("persist.drain")
+                  if trace is not None else NULL_SPAN):
+                drain_cost = session.drain()
             metrics.histogram("persist.drain_s",
                               phase="recovery").observe(drain_cost)
-            if trace is not None:
-                trace.event("persist.drain", cost_s=drain_cost)
             assert self.snapshot is not None, \
                 "no completed persistence run before failure"
             k_rec = int(self.snapshot.k)
@@ -926,6 +947,8 @@ class PersistencePipeline:
                 # enlarged union.
                 nxt = overlap_queue.pop(0)
                 new = list(nxt.blocks)
+                if new:
+                    self.inject_times.append(time.perf_counter())
                 prd_hit = nxt.prd
                 metrics.counter("recovery.restart").inc()
                 if trace is not None:
@@ -979,6 +1002,14 @@ class PersistencePipeline:
                                      solver.state_vector_fields)
             return st_new
 
+    def note_dispatch(self, t: float) -> None:
+        """The next step is dispatched at host time ``t``: every recovery
+        since the previous dispatch ends here (``recovery.wall_s``)."""
+        hist = self.metrics.histogram("recovery.wall_s", phase="recovery")
+        for t_fail in self.inject_times:
+            hist.observe(t - t_fail)
+        self.inject_times.clear()
+
     # ------------------------------------------------------------------
     def finalize(self, report: SolveReport, state, bnorm: float) -> None:
         """Exit drain + derived-view readback (DESIGN.md §9): a staged
@@ -991,7 +1022,10 @@ class PersistencePipeline:
         self.persist_commit(0.0)
         metrics = self.metrics
         report.iterations = int(state.k)
-        report.final_relres = self.solver.residual_norm(state) / bnorm
+        trace = self.trace
+        with (trace.span("solve.residual", k=report.iterations)
+              if trace is not None else NULL_SPAN):
+            report.final_relres = self.solver.residual_norm(state) / bnorm
         report.converged = (report.converged
                             or report.final_relres < self.config.tol)
         report.wasted_iterations = metrics.counter_value(
@@ -999,6 +1033,8 @@ class PersistencePipeline:
         report.failures_recovered = metrics.counter_value("recovery.absorbed")
         report.recovery_restarts = metrics.counter_value("recovery.restart")
         report.storage_failures = metrics.counter_value("storage.kill")
+        report.recovery_wall_s = metrics.histogram_total("recovery.wall_s",
+                                                         phase="recovery")
         report.persist_events = metrics.counter_value("persist.commit")
         report.persist_aborts = metrics.counter_value("persist.abort")
         report.persist_cost_s = metrics.histogram_total("persist.commit_s",
@@ -1031,7 +1067,6 @@ class PersistencePipeline:
             "recovery.fetch_bytes", "shard")
         metrics.gauge("solve.iterations").set(report.iterations)
         metrics.gauge("solve.converged").set(1.0 if report.converged else 0.0)
-        trace = self.trace
         if trace is not None:
             trace.event("solve.end", iterations=report.iterations,
                         converged=report.converged,
@@ -1130,7 +1165,9 @@ def solve(
         if k in capture_states_at:
             captured[k] = state
 
-        relres = solver.residual_norm(state) / bnorm
+        with (trace.span("solve.residual", k=k)
+              if trace is not None else NULL_SPAN):
+            relres = solver.residual_norm(state) / bnorm
         report.residual_history.append(relres)
         if relres < config.tol:
             report.converged = True
@@ -1145,6 +1182,8 @@ def solve(
             continue
 
         t0 = time.perf_counter()
+        if pipe.inject_times:
+            pipe.note_dispatch(t0)
         if trace is None:          # identity guard: the disabled hot path
             state = step(state)    # runs zero tracer callables
         else:
