@@ -71,7 +71,7 @@ from repro.core.spmv import make_det_dot
 from repro.distributed.sharding import ShardLayout
 from repro.obs.metrics import MetricsRegistry
 from repro.serving.trace import ServiceRequest
-from repro.solvers.base import base_operator
+from repro.solvers.base import base_operator, device_norm
 from repro.solvers.driver import (
     PersistencePipeline,
     SolveConfig,
@@ -183,7 +183,7 @@ class _Tenant:
         self.period = config.persistence_period
         self.capture_at = frozenset(int(k) for k in capture_at)
         self.captured: Dict[int, object] = {}
-        self.bnorm = float(np.linalg.norm(np.asarray(self.b)))
+        self.bnorm = device_norm(self.op, self.b)
         self.backend = backend
         self.ticket = ticket
 
@@ -559,7 +559,7 @@ class SolveService:
             st_t = t.unpad(st)
             if k in t.capture_at:
                 t.captured[k] = st_t
-            relres = t.solver.residual_norm(st_t) / t.bnorm
+            relres = device_norm(t.op, st_t.r) / t.bnorm
             t.report.residual_history.append(relres)
             if relres < t.tol:
                 t.report.converged = True

@@ -10,7 +10,8 @@ solver supplies:
 
 - ``init_state`` / ``make_step``: the jitted iteration over a NamedTuple
   state pytree that carries an integer ``k`` (completed iterations) and
-  a residual vector ``r`` (for convergence monitoring).
+  a residual vector ``r`` (for convergence monitoring: the driver reads
+  ``||r||`` through :func:`device_norm`, reduced on the device).
 - ``recovery_set``: extraction of the minimal persisted payload.
 - ``reconstruct``: the paper's Algorithm 3/5 pattern — rebuild the failed
   shards exactly from persisted + surviving + static data.
@@ -19,8 +20,11 @@ solver supplies:
 from __future__ import annotations
 
 import abc
+import functools
+import math
 from typing import Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -36,6 +40,33 @@ def solver_dot(op):
     from repro.core.spmv import make_det_dot
 
     return make_det_dot(op.nblocks, getattr(op, "mesh", None))
+
+
+@functools.lru_cache(maxsize=None)
+def _sq_norm(nblocks: int, mesh):
+    """The jitted ``v -> det_dot(v, v)`` for one block layout, built once
+    so a solve's warm-up traces it and later calls reuse the program."""
+    from repro.core.spmv import make_det_dot
+
+    dot = make_det_dot(nblocks, mesh)
+
+    def sq_norm(v):
+        return dot(v, v)
+
+    return jax.jit(sq_norm)
+
+
+def device_norm(op, v) -> float:
+    """``||v||_2`` of a (possibly device-sharded) vector in ``op``'s block
+    layout: the convergence norm of every solver, solo and in the service.
+
+    The sum of squares is :func:`solver_dot`'s order-pinned reduction, run
+    on the device, so the bits are the same on any shard count (DESIGN.md
+    §10); only that f64 scalar comes back to the host, which takes an
+    IEEE square root of it.  Nothing of ``v`` itself is copied to the
+    host."""
+    sq = _sq_norm(op.nblocks, getattr(op, "mesh", None))(v)
+    return math.sqrt(float(sq))
 
 
 def base_operator(op):
@@ -117,12 +148,6 @@ class RecoverableSolver(abc.ABC):
         return {}
 
     # ------------------------------------------------------------------
-    def residual_norm(self, state) -> float:
-        # Host-side numpy norm: gathers the (possibly device-sharded)
-        # residual and reduces in a fixed order, so the convergence check
-        # reads the same bits whether the solve is sharded or not.
-        return float(np.linalg.norm(np.asarray(state.r)))
-
     def wipe(self, state, partition, blocks):
         """Simulate failure: failed shards of every distributed vector (and
         any non-replicated reduction scalar) become garbage."""

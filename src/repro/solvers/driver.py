@@ -482,7 +482,7 @@ class SolveReport:
       is the number of recoveries that reached a step).
     - ``converged`` — relative residual reached ``SolveConfig.tol``.
     - ``final_relres`` — ``||b - A x|| / ||b||`` proxy at exit
-      (``solver.residual_norm / ||b||``).
+      (``||r|| / ||b||``, both by :func:`~repro.solvers.base.device_norm`).
     - ``residual_history`` — the relative residual at the top of every
       main-loop pass (recovered iterations appear twice, by design).
     - ``solver`` — the solver's registry name.
@@ -1018,6 +1018,8 @@ class PersistencePipeline:
         OUT of the registry the loop incremented, so registry and report
         agree by construction (check_report_consistency re-verifies;
         check_trace_report closes the triangle to the trace)."""
+        from repro.solvers.base import device_norm
+
         self.flush_pending_stage()  # a deferred final event still stages
         self.persist_commit(0.0)
         metrics = self.metrics
@@ -1025,7 +1027,7 @@ class PersistencePipeline:
         trace = self.trace
         with (trace.span("solve.residual", k=report.iterations)
               if trace is not None else NULL_SPAN):
-            report.final_relres = self.solver.residual_norm(state) / bnorm
+            report.final_relres = device_norm(self.op, state.r) / bnorm
         report.converged = (report.converged
                             or report.final_relres < self.config.tol)
         report.wasted_iterations = metrics.counter_value(
@@ -1127,6 +1129,8 @@ def solve(
     :class:`PersistencePipeline`; this function owns the state, the
     jitted step, and the loop.
     """
+    from repro.solvers.base import device_norm
+
     trace = config.tracer or None
     if trace is not config.tracer:
         # Normalize the falsy tracer away HERE so the pipeline's own
@@ -1147,8 +1151,7 @@ def solve(
 
         state = place_state(state, pipe.mesh, solver.state_vector_fields)
     step = solver.make_step(op, precond)
-    # host-side norm: gathers a sharded b and reduces deterministically
-    bnorm = float(np.linalg.norm(np.asarray(b)))
+    bnorm = device_norm(op, b)
     report = SolveReport(solver=solver.name, persist_mode=config.persist_mode,
                          metrics=pipe.metrics)
     captured: Dict[int, object] = {}
@@ -1167,7 +1170,7 @@ def solve(
 
         with (trace.span("solve.residual", k=k)
               if trace is not None else NULL_SPAN):
-            relres = solver.residual_norm(state) / bnorm
+            relres = device_norm(op, state.r) / bnorm
         report.residual_history.append(relres)
         if relres < config.tol:
             report.converged = True

@@ -144,3 +144,39 @@ def test_sharded_bit_exactness_failures_and_traffic(multi_device):
     # halving the shard count doubles the bytes a recovery must move
     assert scaling[2]["fetch"] == 2 * scaling[4]["fetch"]
     assert scaling[4]["fetch"] == 2 * scaling[8]["fetch"]
+
+
+_NORM_SUB = r"""
+import json
+import jax
+jax.config.update("jax_enable_x64", True)
+from repro.core.poisson import make_poisson_problem, PRECONDITIONERS
+from repro.distributed.sharding import shard_problem
+from repro.solvers import driver as drv
+from repro.solvers.registry import make_solver
+
+op, b = make_poisson_problem(8, 8, 8, nblocks=4)
+pre = PRECONDITIONERS["jacobi"](op)
+sop, sb = shard_problem(op, b, 4)
+out = {}
+for label, the_op, the_b in (("one", op, b), ("four", sop, sb)):
+    _, rep, _ = drv.solve(make_solver("pcg", the_op, pre), the_op, the_b,
+                          pre, config=drv.SolveConfig(tol=1e-10))
+    out[label] = {"history": [v.hex() for v in rep.residual_history],
+                  "final": rep.final_relres.hex(), "nshards": rep.nshards,
+                  "converged": rep.converged}
+print(json.dumps(out))
+"""
+
+
+@pytest.mark.multi_device
+def test_sharded_convergence_norm_is_bitwise_identical(multi_device):
+    """The device norm (DESIGN.md §10): a PCG solve on one device and
+    on four shards reads the same residual history and final relres,
+    bit for bit."""
+    out = multi_device.run(_NORM_SUB, ndevices=4, timeout=600)
+    one, four = out["one"], out["four"]
+    assert (one["nshards"], four["nshards"]) == (1, 4)
+    assert one["converged"] and len(one["history"]) > 10
+    assert four["history"] == one["history"]
+    assert four["final"] == one["final"]

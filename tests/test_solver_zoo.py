@@ -250,3 +250,91 @@ def test_legacy_backend_api_still_serves_pcg():
     bs = op.partition.block_size
     np.testing.assert_array_equal(prev.p, p0[bs:3 * bs])
     np.testing.assert_array_equal(cur.p, p1[bs:3 * bs])
+
+
+# ----------------------------------------------------------------------
+# The convergence norm is reduced on the device (DESIGN.md §10)
+# ----------------------------------------------------------------------
+@pytest.mark.parametrize("solver_name", sorted(SOLVERS))
+def test_device_norm_matches_numpy(solver_name):
+    """``device_norm`` of each solver's residual, a few iterations in,
+    equals the host numpy 2-norm up to the change of summation order."""
+    from repro.solvers.base import device_norm
+
+    op, b, pre = _problem()
+    _, opts = SOLVER_CASES[solver_name]
+    solver = make_solver(solver_name, op, pre, **opts)
+    state = solver.init_state(op, pre, b)
+    step = solver.make_step(op, pre)
+    for _ in range(3):
+        state = step(state)
+    for v in (b, state.r):
+        want = np.linalg.norm(np.asarray(v))
+        assert abs(device_norm(op, v) - want) <= 1e-14 * want
+
+
+@pytest.fixture
+def host_pulls(monkeypatch):
+    """Every conversion of a jax array to numpy, through ``np.asarray``,
+    ``np.array`` or the array's own ``_value`` (``float``, ``int``,
+    ``tolist``): ``(size, names of the functions on the stack)``.  A
+    constant that a jitted program closes over, read once as XLA lowers
+    the program, is not a pull of the loop's and is left out."""
+    import sys
+
+    from jax._src.array import ArrayImpl
+
+    seen = []
+
+    def record(a):
+        if isinstance(a, jax.Array):
+            names, f = set(), sys._getframe(2)
+            while f is not None:
+                names.add(f.f_code.co_name)
+                f = f.f_back
+            if "_array_mlir_constant_handler" not in names:
+                seen.append((a.size, names))
+
+    def spying(convert):
+        def spy(a, *args, **kwargs):
+            record(a)
+            return convert(a, *args, **kwargs)
+        return spy
+
+    value = ArrayImpl._value
+
+    def value_spy(self):
+        record(self)
+        return value.fget(self)
+
+    monkeypatch.setattr(np, "asarray", spying(np.asarray))
+    monkeypatch.setattr(np, "array", spying(np.array))
+    monkeypatch.setattr(ArrayImpl, "_value", property(value_spy))
+    return seen
+
+
+def test_unprotected_solve_pulls_no_vector_to_the_host(host_pulls):
+    """bnorm, every loop-top check and ``finalize`` bring back one
+    scalar each: no array of more than one element reaches numpy."""
+    op, b, pre = _problem()
+    solver = make_solver("pcg", op, pre)
+    state, report, _ = solve(solver, op, b, pre,
+                             SolveConfig(tol=0.0, maxiter=4))
+    assert len(report.residual_history) == 4 and report.final_relres > 0
+    assert [size for size, _ in host_pulls if size > 1] == []
+    np.asarray(state.r)  # and the spy does see a vector pull
+    assert host_pulls[-1][0] == op.n
+
+
+def test_persisting_solve_pulls_only_the_recovery_set(host_pulls):
+    """An nvm-prd solve copies p (its recovery set) to the host at each
+    persistence point, and no other vector: r stays on the device."""
+    op, b, pre = _problem()
+    solver = make_solver("pcg", op, pre)
+    backend = make_backend("nvm-prd", op, solver=solver)
+    _, report, _ = solve(solver, op, b, pre,
+                         SolveConfig(tol=0.0, maxiter=4), backend=backend)
+    assert report.persist_events == 5
+    vectors = [names for size, names in host_pulls if size > 1]
+    assert len(vectors) >= report.persist_events
+    assert all("recovery_set" in names for names in vectors)

@@ -147,6 +147,27 @@ def test_pcg_step_f64_compiles_for_v5e(one_chip, no_compile_cache):
             + mem.temp_size_in_bytes) < V5E_HBM_BYTES
 
 
+def test_device_norm_compiles_for_v5e(topo, one_chip, no_compile_cache):
+    """The convergence norm's program at the pcg_1g block shape, on one
+    chip and z-sharded over four: it reads the f64 vector and returns
+    one f64 scalar, the only bytes the host pulls."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.solvers.base import _sq_norm
+
+    n = 16 * 1024 * 1024
+    mesh = Mesh(topo.devices, ("data",))
+    for m, sharding in ((None, one_chip),
+                        (mesh, NamedSharding(mesh, P("data")))):
+        lowered = _sq_norm(8, m).lower(_spec((n,), jnp.float64, sharding))
+        assert (lowered.out_info.shape, lowered.out_info.dtype) == (
+            (), jnp.float64)
+        mem = lowered.compile().memory_analysis()
+        assert mem.argument_size_in_bytes >= n * 8 // (1 if m is None else 4)
+        # the scalar's output buffer is one padded tile, not a vector
+        assert mem.output_size_in_bytes <= 1024
+
+
 def test_sharded_pcg_compiles_for_v5e_2x2(topo, no_compile_cache):
     """The same grid z-sharded over four chips: the step (halo
     exchange as collective-permutes) and the stencil alone, as
