@@ -3,7 +3,9 @@ must move, from its shapes.
 
 Least bytes count what any implementation has to read and write, so a
 share of the roofline built on them stays under 100% however the
-program fuses its work.
+program fuses its work.  A reader sets them against the device time of
+the same work: a sharded step's time is per chip and its bytes are one
+chip's share (``n / chips`` unknowns).
 """
 from __future__ import annotations
 

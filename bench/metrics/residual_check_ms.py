@@ -1,6 +1,7 @@
 """``residual_check_ms`` (ms per iteration): wall time of the program's
-``solve.residual`` spans, the pull of r and its host norm at the top of
-every loop pass, over the iterations the window completed."""
+``solve.residual`` spans, the convergence check at the top of every
+loop pass (r.r reduced on the device, one scalar pulled, its square
+root on the host), over the iterations the window completed."""
 
 from bench import host_phases
 

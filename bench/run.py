@@ -8,7 +8,14 @@ One run is one ``api.solve`` of the cell's deployment with its failure
 campaign (the window).  Set-up draws the right-hand side on the device
 from the seed, then runs a short warm-up solve with the cell's own
 resilience spec and one failure of each kind the window will see, on
-the same blocks, so the window compiles nothing.  The window's work is
+the same blocks or shard, so the window compiles nothing.  A cell of
+more than one chip lays the problem over that many devices, one shard
+each (``Problem.with_shards`` over the configuration's ``chips``, which
+``ResilienceSpec(nshards=...)`` pins), and draws the right-hand side
+straight into that layout; its traffic may kill a whole shard
+(``"unit": "shard"``).  A cell reads the per-layer metrics whose
+``workloads`` list names it, a ``<metric>.<variant>`` through
+``<metric>``'s reader (:mod:`bench.spec`).  The window's work is
 fixed: ``N = --seconds x iterations_per_second`` of the configuration
 (its pace on the chip when the cell was defined), and the failures land
 at their fractions of ``N``.
@@ -20,13 +27,20 @@ one JSON object: ``correct``, ``attempted`` (iterations and failures
 scheduled), ``failed`` (those not completed), ``metrics`` (the cell's
 end-to-end metrics with ``--trace 0``, its per-layer metrics with
 ``--trace 1``), ``device`` and, last, ``checks``: each compared number
-with its limit, which also close standard error.
+with its limit, which also close standard error.  ``device`` counts
+the cell's devices; ``memory_peak_bytes`` is the fullest one's peak,
+``memory_peak_bytes_by_device`` each one's.  Every device reading is
+per chip: trace times are averaged over the devices, and the step's
+roofline counts one chip's share of the bytes (the stripe encode, which
+runs whole on one device, its whole stripe over the kernel's time summed
+over the devices).
 
 Without a TPU the run exits 2 and prints no result.  ``--rehearse``
 runs the same path on the host CPU at a tiny grid with the same block
-count and interpreted kernels; ``--control`` runs the program in
-float32, one precision below the configuration's, which the comparison
-has to find wrong.
+count and interpreted kernels, on as many virtual host devices as the
+cell has chips; ``--control`` runs the program in float32, one
+precision below the configuration's, which the comparison has to find
+wrong.
 """
 from __future__ import annotations
 
@@ -35,9 +49,11 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
+import functools  # noqa: E402
 import gc  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
+import re  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import traceback  # noqa: E402
@@ -95,10 +111,12 @@ class CompileCounter:
 
 
 class RunView:
-    """What a per-layer metric reader sees of one run."""
+    """What a per-layer metric reader sees of one run.  ``n`` and
+    ``nblocks`` are the whole problem's, laid over ``chips`` devices;
+    the trace's times are per device."""
 
     def __init__(self, *, trace, records, config, iterations, n, nblocks,
-                 itemsize, device_kind, recoveries):
+                 itemsize, device_kind, recoveries, chips=1):
         self.trace = trace
         self.records = records
         self.config = config
@@ -108,6 +126,7 @@ class RunView:
         self.itemsize = itemsize
         self.device_kind = device_kind
         self.recoveries = recoveries
+        self.chips = chips
 
     def span_per_recovery(self, name):
         if not self.recoveries:
@@ -133,10 +152,77 @@ def step_gaps(records):
     return gaps, recovery
 
 
+def rehearse_on_host(chips: int) -> None:
+    """Point JAX, before it starts, at the host CPU with ``chips``
+    virtual devices, so a cell's sharded path runs as it would on its
+    chips."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    if chips > 1:
+        flags = re.sub(r"--xla_force_host_platform_device_count=\S*", "",
+                       os.environ.get("XLA_FLAGS", ""))
+        os.environ["XLA_FLAGS"] = (
+            f"{flags} --xla_force_host_platform_device_count={chips}".strip())
+
+
+def data_mesh(chips):
+    """The 1-D ``data`` mesh a cell of ``chips`` devices lays its
+    problem over, or None on one chip."""
+    from repro.distributed.sharding import make_data_mesh
+
+    return make_data_mesh(chips) if chips > 1 else None
+
+
+def draw_rhs(key, n, mesh):
+    """The f64 right-hand side of ``n`` unknowns drawn on the device from
+    ``key``; on a ``mesh``, straight into the problem's layout, so the
+    harness puts no whole vector on one device."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    layout = ({} if mesh is None else
+              {"out_shardings": NamedSharding(mesh, PartitionSpec("data"))})
+
+    @functools.partial(jax.jit, **layout)
+    def make_rhs(key):
+        return jax.random.normal(jax.random.key(key), (n,), jnp.float64)
+
+    return make_rhs(key)
+
+
+def build_problem(cfg, grid, b, mesh):
+    """The cell's problem with right-hand side ``b``, in ``b``'s
+    precision, and its resilience spec.  On a ``mesh`` the problem is
+    laid over its devices and the spec pins that layout; without one,
+    the unsharded problem and no pin."""
+    from repro import api
+    from repro.core.poisson import JacobiPreconditioner, StencilOperator
+
+    dtype = b.dtype
+    op = StencilOperator(*grid, nblocks=cfg["nblocks"], dtype=dtype)
+    problem = api.Problem.from_parts(op, b, JacobiPreconditioner(op))
+    nshards = None
+    if mesh is not None:
+        nshards = mesh.size
+        problem = problem.with_shards(nshards, mesh=mesh)
+    resilience = api.ResilienceSpec(
+        cfg["backend"], persist_mode=cfg["persist_mode"],
+        period=cfg["period"], fused_persist=cfg["fused_persist"],
+        dtype=dtype, nshards=nshards)
+    return problem, resilience
+
+
+def campaign(events):
+    """The program's failure campaign for planned events."""
+    from repro import api
+
+    return [api.FailureEvent(blocks=e.blocks, shard=e.shard,
+                             at_iteration=e.at_iteration, prd=e.storage)
+            for e in events]
+
+
 def main(argv=None) -> int:
     args = parse_args(argv)
-    if args.rehearse:
-        os.environ["JAX_PLATFORMS"] = "cpu"
     # libtpu would otherwise log to a fixed directory under /tmp
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     for path in (os.path.join(ROOT, "src"), ROOT):
@@ -146,9 +232,10 @@ def main(argv=None) -> int:
 
     cell = spec.load_cell(args.workload)
     cfg = cell.config
+    if args.rehearse:
+        rehearse_on_host(cell.chips)
 
     import jax
-    import jax.numpy as jnp
     import numpy as np
 
     devices = jax.devices()
@@ -168,7 +255,6 @@ def main(argv=None) -> int:
     # cell in this checkout compiles nothing
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     from repro import api
-    from repro.core.poisson import JacobiPreconditioner, StencilOperator
     from repro.launch.cache import enable_compile_cache
     from repro.obs import Tracer
 
@@ -183,24 +269,12 @@ def main(argv=None) -> int:
     n = grid[0] * grid[1] * grid[2]
     rng = np.random.default_rng(args.seed)
     rhs_key = int(rng.integers(2**31))
-    blocks = spec.draw_blocks(cell.traffic, nblocks, rng)
+    blocks = spec.draw_blocks(cell.traffic, nblocks, rng, cell.chips)
 
-    @jax.jit
-    def make_rhs(key):
-        return jax.random.normal(jax.random.key(key), (n,), jnp.float64)
-
-    b64 = make_rhs(rhs_key)
-    b = b64 if dtype == np.float64 else b64.astype(dtype)
-    op = StencilOperator(*grid, nblocks=nblocks, dtype=dtype)
-    problem = api.Problem.from_parts(op, b, JacobiPreconditioner(op))
-    resilience = api.ResilienceSpec(
-        cfg["backend"], persist_mode=cfg["persist_mode"],
-        period=cfg["period"], fused_persist=cfg["fused_persist"],
-        dtype=dtype)
-
-    def campaign(events):
-        return [api.FailureEvent(blocks=e.blocks, at_iteration=e.at_iteration,
-                                 prd=e.storage) for e in events]
+    mesh = data_mesh(cell.chips)
+    b64 = draw_rhs(rhs_key, n, mesh)
+    problem, resilience = build_problem(
+        cfg, grid, b64 if dtype == np.float64 else b64.astype(dtype), mesh)
 
     def solve(iterations, events, tracer):
         return api.solve(problem,
@@ -224,11 +298,12 @@ def main(argv=None) -> int:
                      int(round(args.seconds * cfg["iterations_per_second"])))
     events = spec.failure_events(cell.traffic, iterations, blocks)
     say(workload=args.workload, seed=args.seed, grid=grid,
-        dtype=dtype.name, warmup_s=round(warm_s, 3),
+        dtype=dtype.name, chips=cell.chips, warmup_s=round(warm_s, 3),
         warmup_step_gaps_s=[round(g, 4) for g in gaps],
         warmup_recovery_s=[round(r, 3) for r in warm_recovery],
         iterations=iterations,
-        events=[(e.at_iteration, e.blocks, e.storage) for e in events])
+        events=[(e.at_iteration, e.blocks, e.shard, e.storage)
+                for e in events])
 
     # ---- the window ----
     tracer = Tracer()
@@ -254,11 +329,12 @@ def main(argv=None) -> int:
     compiles.active = False
     if args.trace:
         jax.profiler.stop_trace()
-    stats = dev.memory_stats() or {}
+    peaks = [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+             for d in devices[:cell.chips]]
     device = {"platform": dev.platform, "kind": dev.device_kind,
-              "count": len(devices),
-              "memory_peak_bytes": int(stats.get("peak_bytes_in_use", 0))}
-    block_events = [e for e in events if e.blocks]
+              "count": len(peaks), "memory_peak_bytes": max(peaks),
+              "memory_peak_bytes_by_device": peaks}
+    block_events = [e for e in events if e.loses_blocks]
     attempted = iterations + len(events)
     say(window_s=round(window_s, 4), compiled_in_window=compiles.compiled,
         loaded_from_cache_in_window=compiles.loaded,
@@ -336,7 +412,7 @@ def main(argv=None) -> int:
                            iterations=rep.iterations, n=n, nblocks=nblocks,
                            itemsize=dtype.itemsize,
                            device_kind=dev.device_kind,
-                           recoveries=len(recovery))
+                           recoveries=len(recovery), chips=cell.chips)
             for m in cell.per_layer:
                 v = spec.optional(spec.load_reader(m["name"])(view))
                 if v is not None:

@@ -77,6 +77,24 @@ def rs_reconstruct(shards, k):
     return out
 gf256.rs_reconstruct = rs_reconstruct
 """,
+    # the exchange between chips left out: the stencil of a sharded
+    # problem misses the neighbour shard's plane at every slab boundary,
+    # as if no halo arrived
+    "halo_dropped": """
+import jax.numpy as jnp
+from repro.distributed import sharding
+_apply = sharding.ShardedOperator.apply
+def apply(self, x):
+    nz, ny, nx = self.base.grid
+    u = x.reshape(nz, ny, nx)
+    z = jnp.arange(nz)[:, None, None]
+    nzl = nz // self.layout.nshards
+    lost = (jnp.where((z % nzl == 0) & (z > 0), jnp.roll(u, 1, axis=0), 0)
+            + jnp.where((z % nzl == nzl - 1) & (z < nz - 1),
+                        jnp.roll(u, -1, axis=0), 0))
+    return _apply(self, x) + lost.reshape(-1)
+sharding.ShardedOperator.apply = apply
+""",
 }
 
 CASES = [
@@ -89,19 +107,19 @@ CASES = [
 ]
 
 
-def run_patched(workload, patch, extra, cache_dir):
-    driver = (f"import sys; sys.path[:0] = [{spec.ROOT!r} + '/src', "
-              f"{spec.ROOT!r}]\n" + patch +
+def run_patched(workload, patch, extra, cache_dir, root=spec.ROOT):
+    script = (f"import sys; sys.path[:0] = [{root!r} + '/src', "
+              f"{root!r}]\n" + patch +
               "\nfrom bench import run\nsys.exit(run.main(sys.argv[1:]))\n")
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     env["JAX_COMPILATION_CACHE_DIR"] = str(cache_dir)
     env.pop("XLA_FLAGS", None)
     res = subprocess.run(
-        [sys.executable, "-c", driver, "--workload", workload, "--seed",
+        [sys.executable, "-c", script, "--workload", workload, "--seed",
          "2147483700", "--seconds", "0.5", "--trace", "0", "--rehearse",
          *extra], capture_output=True, text=True, env=env, timeout=300,
-        cwd=spec.ROOT)
+        cwd=root)
     assert res.returncode == 0, res.stderr[-3000:]
     return json.loads(res.stdout.strip().splitlines()[-1])
 
@@ -124,3 +142,14 @@ def test_the_float32_control_reads_incorrect(workload, cache_dir):
     failed = [k for k, c in out["checks"].items()
               if c["value"] is None or c["value"] > c["limit"]]
     assert failed, out["checks"]
+
+
+def test_a_sharded_solve_without_its_halo_exchange_reads_incorrect(
+        candidate_root, cache_dir):
+    from bench.conftest import CANDIDATE
+
+    out = run_patched(CANDIDATE, FAULTS["halo_dropped"], [], cache_dir,
+                      root=candidate_root)
+    assert out["device"]["count"] == 4
+    assert out["correct"] is False, out["checks"]
+    assert out["checks"]["x_err"]["value"] > out["checks"]["x_err"]["limit"]

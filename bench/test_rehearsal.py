@@ -1,6 +1,7 @@
 """The harness command end to end on the host CPU: ``--rehearse`` runs a
-tiny grid with the cell's block count and prints a well-formed result
-line; without it, a CPU run exits non-zero and prints no result."""
+tiny grid with the cell's block count, on as many virtual devices as the
+cell has chips, and prints a well-formed result line; without it, a CPU
+run exits non-zero and prints no result."""
 import json
 import os
 import subprocess
@@ -10,18 +11,18 @@ import pytest
 
 from bench import spec
 
-RUN = os.path.join(spec.ROOT, "bench", "run.py")
 CELLS = [w["name"] for w in spec.load_benchmark()["workloads"]]
 
 
-def run_bench(args, cache_dir, timeout=300):
+def run_bench(args, cache_dir, timeout=300, root=spec.ROOT):
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     env["JAX_COMPILATION_CACHE_DIR"] = str(cache_dir)
     env.pop("XLA_FLAGS", None)
-    return subprocess.run([sys.executable, RUN, *map(str, args)],
-                          capture_output=True, text=True, env=env,
-                          timeout=timeout, cwd=spec.ROOT)
+    return subprocess.run(
+        [sys.executable, os.path.join(root, "bench", "run.py"),
+         *map(str, args)], capture_output=True, text=True, env=env,
+        timeout=timeout, cwd=root)
 
 
 def last_json(res):
@@ -67,3 +68,25 @@ def test_a_cpu_run_without_the_rehearsal_switch_fails(cache_dir):
                      "--trace", 0], cache_dir)
     assert res.returncode != 0
     assert "{" not in res.stdout
+
+
+@pytest.mark.parametrize("trace", [0, 1])
+def test_a_sharded_rehearsal_kills_a_shard_and_recovers(candidate_root,
+                                                       cache_dir, trace):
+    from bench.conftest import CANDIDATE
+
+    out = last_json(run_bench(["--workload", CANDIDATE, "--seed",
+                               2**31 + 17, "--seconds", 0.5, "--trace",
+                               trace, "--rehearse"], cache_dir,
+                              root=candidate_root))
+    assert out["correct"] is True, out["checks"]
+    assert out["failed"] == 0 and out["checks"]["recovered"]["value"] == 0
+    assert out["device"]["count"] == 4
+    assert len(out["device"]["memory_peak_bytes_by_device"]) == 4
+    assert out["device"]["memory_peak_bytes"] == max(
+        out["device"]["memory_peak_bytes_by_device"])
+    # the CPU trace has no TPU plane: of the metrics the cell reads, the
+    # recovery spans read, the device and host-phase readers stay silent
+    spans = {"recovery_fetch_s", "recovery_reconstruct_s"}
+    assert set(out["metrics"]) == ({"iter_ms", "setup_s"} if trace == 0
+                                   else {f"{m}.{CANDIDATE}" for m in spans})

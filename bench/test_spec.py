@@ -129,3 +129,166 @@ def test_reference_stencil_by_hand():
     # PCG on a 1x1x1 grid solves 6 x = b in one step
     x, kept = ref.pcg(np.array([3.0]), (1, 1, 1), 1, keep_p=(0,))
     assert x[0] == 0.5 and kept[0][0] == 0.5
+
+
+def _write(root, rel, doc):
+    path = os.path.join(root, rel)
+    os.makedirs(os.path.dirname(path), exist_ok=True)
+    with open(path, "w") as f:
+        json.dump(doc, f)
+
+
+def _today(cell):
+    """The per-layer metrics whose own ``workloads`` list names ``cell``:
+    what each accepted cell read before this harness ran sharded cells."""
+    return [m["name"] for m in BENCH["per_layer"]
+            if cell in m.get("workloads", [cell])]
+
+
+@pytest.mark.parametrize("change, error", [
+    # a one-chip cell of the four-chip configuration
+    ({"workload": {"chips": 1}}, ValueError),
+    # a four-chip cell of a one-chip configuration
+    ({"workload": {"config": "pcg1g-nvmprd"}}, ValueError),
+    # a shard failure on a one-chip cell
+    ({"workload": {"config": "pcg1g-nvmprd", "chips": 1}}, ValueError),
+    # a failure of a unit the generator does not know
+    ({"traffic": {"unit": "rack"}}, ValueError),
+    # a per-layer metric of the cell that no reader serves
+    ({"metric": "no_such_metric.sharded"}, FileNotFoundError),
+])
+def test_load_cell_refuses_a_cell_its_layout_cannot_run(candidate_root,
+                                                       change, error):
+    from bench.conftest import CANDIDATE
+
+    spec.load_cell(CANDIDATE, root=candidate_root)
+    bench = spec.load_benchmark(candidate_root)
+    bench["workloads"][-1].update(change.get("workload", {}))
+    if "traffic" in change:
+        traffic = spec.load_traffic("shardkill", candidate_root)
+        traffic["failures"][0].update(change["traffic"])
+        _write(candidate_root, "bench/traffic/shardkill.json", traffic)
+    if "metric" in change:
+        bench["per_layer"].append(dict(bench["per_layer"][0],
+                                       name=change["metric"],
+                                       workloads=[CANDIDATE]))
+    _write(candidate_root, "BENCHMARK.json", bench)
+    with pytest.raises(error):
+        spec.load_cell(CANDIDATE, root=candidate_root)
+
+
+def test_a_shard_failure_draws_one_shard_from_the_seed(candidate_root):
+    from bench.conftest import CANDIDATE
+
+    cell = spec.load_cell(CANDIDATE, root=candidate_root)
+    assert cell.chips == cell.config["chips"] == 4
+
+    def draw(seed):
+        rng = np.random.default_rng(seed)
+        rhs_key = int(rng.integers(2**31))
+        return rhs_key, spec.draw_blocks(cell.traffic, 32, rng, cell.chips)
+
+    draws = [draw(2**31 + 9 + s) for s in range(40)]
+    assert draws == [draw(2**31 + 9 + s) for s in range(40)]
+    assert {d[1][0] for d in draws} == {0, 1, 2, 3}
+    (ev,) = spec.failure_events(cell.traffic, 13, draws[0][1])
+    assert ev.at_iteration == 6 and ev.shard == draws[0][1][0]
+    assert ev.blocks == () and not ev.storage and ev.loses_blocks
+    (warm,) = spec.warmup_events(cell.traffic, draws[0][1])
+    assert warm.at_iteration == 2 and warm.shard == ev.shard
+
+
+#: (rhs_key, draws) of the accepted cells, as the generator gave them
+#: before a failure could kill a shard
+ACCEPTED_DRAWS = {1: (1016164991, [(4,)]),
+                  2**31 + 3: (985173613, [(5,)]),
+                  2147484003: (1690033565, [(3,)]),
+                  2**33 + 5: (1702598247, [(1,)])}
+
+
+@pytest.mark.parametrize("seed", sorted(ACCEPTED_DRAWS))
+@pytest.mark.parametrize("workload", [w["name"] for w in BENCH["workloads"]])
+def test_the_accepted_cells_draw_what_they_drew(workload, seed):
+    cell = spec.load_cell(workload)
+    rng = np.random.default_rng(seed)
+    rhs_key = int(rng.integers(2**31))
+    draws = spec.draw_blocks(cell.traffic, cell.config["nblocks"], rng,
+                             cell.chips)
+    key, blocks = ACCEPTED_DRAWS[seed]
+    assert rhs_key == key
+    assert draws == blocks[:len(cell.traffic["failures"])]
+    for ev in spec.failure_events(cell.traffic, 17, draws):
+        assert ev.shard is None and ev.blocks in blocks
+
+
+#: each roofline on the recorded TPU trace at one chip's share of the
+#: step (65536 f64 unknowns, 8 blocks, one 6+2 encode), as the readers
+#: computed it before they knew the chip count
+ROOFLINE_ONE_CHIP = {"step_hbm_roofline": 89.01362060110611,
+                     "gf256_encode_roofline": 33.78000340025656}
+
+
+@pytest.mark.parametrize("metric", sorted(ROOFLINE_ONE_CHIP))
+def test_rooflines_count_one_chips_share(metric):
+    from bench import trace_reduce
+    from bench.run import RunView
+    from bench.test_trace_reduce import TPU_TRACE
+
+    trace = trace_reduce.load(TPU_TRACE)
+    encode = {"type": "span", "name": "gf256.rs_encode", "ts": 0.0,
+              "dur": 1e-3, "depth": 0, "args": {}}
+
+    def read(n, nblocks, chips):
+        return spec.load_reader(metric)(RunView(
+            trace=trace, records=[encode],
+            config={"stripe_data": 6, "stripe_parity": 2}, iterations=2,
+            n=n, nblocks=nblocks, itemsize=8, device_kind="TPU v5 lite",
+            recoveries=0, chips=chips))
+
+    assert read(65536, 8, 1) == ROOFLINE_ONE_CHIP[metric]
+    four = read(4 * 65536, 32, 4)
+    if metric == "step_hbm_roofline":
+        # four chips, each stepping the same share in the recorded time:
+        # the same reading, a quarter of the whole problem's bytes over
+        # one chip's time
+        assert four == pytest.approx(ROOFLINE_ONE_CHIP[metric], rel=1e-12)
+        assert four == pytest.approx(read(4 * 65536, 32, 1) / 4, rel=1e-12)
+    else:
+        # the encode takes the whole stripe on the one device that ran
+        # it: four times the bytes in the recorded kernel time
+        assert four == pytest.approx(4 * ROOFLINE_ONE_CHIP[metric],
+                                     rel=1e-12)
+
+
+def test_accepted_cells_read_the_metrics_they_read():
+    for w in BENCH["workloads"]:
+        cell = spec.load_cell(w["name"])
+        assert [m["name"] for m in cell.per_layer] == _today(w["name"])
+        for m in cell.per_layer:
+            assert spec.reader_of(m["name"]) == m["name"]
+
+
+def test_a_new_cell_names_existing_metrics_without_editing_them(
+        candidate_root):
+    from bench.conftest import CANDIDATE, CANDIDATE_METRICS, candidate_entry
+
+    doc = spec.load_benchmark(candidate_root)
+    # one new workloads entry and new per-layer entries after the
+    # existing ones; every existing entry as it was
+    assert doc["workloads"][:-1] == BENCH["workloads"]
+    assert doc["workloads"][-1] == candidate_entry()
+    old = len(BENCH["per_layer"])
+    assert doc["per_layer"][:old] == BENCH["per_layer"]
+    assert {k: v for k, v in doc.items()
+            if k not in ("workloads", "per_layer")} == {
+        k: v for k, v in BENCH.items() if k not in ("workloads", "per_layer")}
+    cell = spec.load_cell(CANDIDATE, root=candidate_root)
+    assert cell.per_layer == doc["per_layer"][old:]
+    # each new entry is an existing metric, read by that metric's reader
+    assert [spec.reader_of(m["name"], candidate_root)
+            for m in cell.per_layer] == CANDIDATE_METRICS
+    assert {"step_hbm_roofline", "idle_share"} <= set(CANDIDATE_METRICS)
+    assert [m["name"] for m in cell.end_to_end] == ["iter_ms", "setup_s"]
+    for w in BENCH["workloads"]:
+        old = spec.load_cell(w["name"], root=candidate_root)
+        assert [m["name"] for m in old.per_layer] == _today(w["name"])

@@ -88,10 +88,11 @@ def test_tracer_cost_rehearses_on_the_cpu(tmp_path):
         capture_output=True, text=True, env=env, timeout=300, cwd=spec.ROOT)
     assert res.returncode == 0, res.stderr[-3000:]
     out = json.loads(res.stdout.strip().splitlines()[-1])
-    # the kill lands at the window's middle, as in bench/run.py
-    (at, blocks, storage), = out["events"]
+    # the kill lands at the window's middle, as in bench/run.py; it kills
+    # a block, not a shard
+    (at, blocks, shard, storage), = out["events"]
     assert out["iterations"] == 4 and at == 2 and len(blocks) == 1
-    assert storage is False
+    assert shard is None and storage is False
     assert len(out["plain_ms_per_iter"]) == len(out["traced_ms_per_iter"]) \
         == len(out["pair_diff_us_per_iter"]) == 1
     assert out["records_per_iter"] > 1 and out["tracer_calls_us_per_iter"] > 0

@@ -89,19 +89,20 @@ def main(argv=None) -> int:
     ap.add_argument("--rehearse-size", action="store_true")
     ap.add_argument("--rehearse", action="store_true")
     args = ap.parse_args(argv)
-    if args.rehearse:
-        os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     for path in (os.path.join(ROOT, "src"), ROOT):
         if path not in sys.path:
             sys.path.insert(0, path)
     from bench import spec
-    from bench.run import MIN_ITERATIONS, REHEARSAL_PLANE, WARMUP_ITERATIONS
+    from bench.run import (MIN_ITERATIONS, REHEARSAL_PLANE,
+                           WARMUP_ITERATIONS, build_problem, campaign,
+                           data_mesh, draw_rhs, rehearse_on_host)
 
     cell = spec.load_cell(args.workload)
     cfg = cell.config
+    if args.rehearse:
+        rehearse_on_host(cell.chips)
     import jax
-    import jax.numpy as jnp
     import numpy as np
 
     dev = jax.devices()[0]
@@ -111,7 +112,6 @@ def main(argv=None) -> int:
         return 2
     jax.config.update("jax_enable_x64", True)
     from repro import api
-    from repro.core.poisson import JacobiPreconditioner, StencilOperator
     from repro.launch.cache import enable_compile_cache
     from repro.obs import Tracer
 
@@ -122,20 +122,13 @@ def main(argv=None) -> int:
     n = grid[0] * grid[1] * grid[2]
     rng = np.random.default_rng(args.seed)
     rhs_key = int(rng.integers(2**31))
-    blocks = spec.draw_blocks(cell.traffic, cfg["nblocks"], rng)
-    b = jax.jit(lambda key: jax.random.normal(
-        jax.random.key(key), (n,), jnp.float64))(rhs_key)
-    op = StencilOperator(*grid, nblocks=cfg["nblocks"], dtype=np.float64)
-    problem = api.Problem.from_parts(op, b, JacobiPreconditioner(op))
-    resilience = api.ResilienceSpec(
-        cfg["backend"], persist_mode=cfg["persist_mode"],
-        period=cfg["period"], fused_persist=cfg["fused_persist"],
-        dtype=np.float64)
+    blocks = spec.draw_blocks(cell.traffic, cfg["nblocks"], rng, cell.chips)
+    mesh = data_mesh(cell.chips)
+    problem, resilience = build_problem(
+        cfg, grid, draw_rhs(rhs_key, n, mesh), mesh)
 
     def timed(iterations, events, tracer):
-        failures = [api.FailureEvent(blocks=e.blocks,
-                                     at_iteration=e.at_iteration,
-                                     prd=e.storage) for e in events]
+        failures = campaign(events)
         gc.collect()  # each window starts as run.py's does
         t0 = time.perf_counter()
         res = api.solve(problem, api.SolverSpec(cfg["solver"], tol=0.0,
@@ -167,7 +160,8 @@ def main(argv=None) -> int:
     median_diff_us = statistics.median(diffs_us)
     out = {
         "workload": args.workload, "grid": grid, "iterations": it,
-        "events": [(e.at_iteration, e.blocks, e.storage) for e in events],
+        "events": [(e.at_iteration, e.blocks, e.shard, e.storage)
+                   for e in events],
         "device": {"platform": dev.platform, "kind": dev.device_kind},
         "plain_ms_per_iter": plain_ms,
         "traced_ms_per_iter": [1e3 * t / it for t in traced],
